@@ -19,6 +19,7 @@ import time
 import jax
 
 from benchmarks import common
+from repro.compile_cache import enable_compile_cache
 from repro.core.plan import plan_cache_stats
 
 MODULES = ("table2_scheme1", "table3_scheme2", "table4_transfer",
@@ -119,6 +120,7 @@ def main() -> None:
                          "whose run() accepts trace= (serve_load)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     common.reset_results()
     print("name,us_per_call,derived")
     modules_run: dict = {}

@@ -41,6 +41,7 @@ import time
 import numpy as np
 
 from benchmarks.common import emit, time_fn
+from repro.compile_cache import enable_compile_cache
 from repro.core.spec import GLCMSpec
 from repro.obs.trace import Tracer, set_tracer
 from repro.serve.engine import GLCMEngine, GLCMServeConfig
@@ -243,6 +244,7 @@ def main(argv=None) -> int:
                     help="write Chrome-trace JSON of the continuous "
                          "50%%-load replay here (open in Perfetto)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     run(n_requests=args.requests, trace=args.trace)
     return 0
 

@@ -50,11 +50,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.spec import GLCMSpec
 
-# jax >= 0.6 exposes shard_map at the top level; 0.4.x keeps it experimental.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 __all__ = [
     "glcm_sharded",
     "glcm_sharded_batch",
@@ -207,7 +202,7 @@ def glcm_sharded(
             raise ValueError(
                 f"region grid extent {g0} not divisible by {n_shards} shards"
             )
-        fn = _shard_map(
+        fn = jax.shard_map(
             lambda p: _region_grid_partials(p, local_partial, levels, offset),
             mesh=mesh,
             # out: (*grid, L, L) — len(offset) grid axes + the (L, L) matrix
@@ -244,7 +239,7 @@ def glcm_sharded(
         part = local_partial(ext.astype(jnp.int32), levels, offset, local_n)
         return jax.lax.psum(part, flat_axis)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=P(flat_axis, *([None] * (img.ndim - 1))),
@@ -308,7 +303,7 @@ def glcm_sharded_batch(
             raise ValueError(
                 f"region grid extent {g0} not divisible by {n_rows} shards"
             )
-        fn = _shard_map(
+        fn = jax.shard_map(
             lambda p: _region_grid_partials(p, local_partial, levels, offset),
             mesh=mesh,
             # out: (B, *grid, L, L) — nd grid axes + the (L, L) matrix
@@ -350,7 +345,7 @@ def glcm_sharded_batch(
             part = jax.lax.psum(part, row_axis)
         return part
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=P(batch_axis, row_axis, *([None] * (nd - 1))),

@@ -82,7 +82,10 @@ def _haralick_single(p: jax.Array, select: tuple[int, ...]) -> jax.Array:
 
     f1 = jnp.sum(p**2)
     f2 = jnp.sum((ii - jj) ** 2 * p)
-    f3 = (jnp.sum(ii * jj * p) - mu_x * mu_y) / jnp.maximum(sd_x * sd_y, _EPS)
+    # Centered covariance: Σ ij·p − μxμy cancels catastrophically in float32
+    # when the levels sit far from 0 and the window's variance is small.
+    cov = jnp.sum((ii - mu_x) * (jj - mu_y) * p)
+    f3 = cov / jnp.maximum(sd_x * sd_y, _EPS)
     mu = jnp.sum(p * ii)  # Haralick's μ in f4 (mean of joint over i)
     f4 = jnp.sum((ii - mu) ** 2 * p)
     f5 = jnp.sum(p / (1.0 + (ii - jj) ** 2))
@@ -116,7 +119,9 @@ def _haralick_single(p: jax.Array, select: tuple[int, ...]) -> jax.Array:
         a_mat = p / jnp.sqrt(
             jnp.maximum(px[:, None], _EPS) * jnp.maximum(py[None, :], _EPS)
         )
-        eig = jnp.linalg.eigvalsh(a_mat @ a_mat.T)
+        # HIGHEST: the TPU's default f32 matmul precision is one bf16 pass.
+        gram = jnp.matmul(a_mat, a_mat.T, precision=jax.lax.Precision.HIGHEST)
+        eig = jnp.linalg.eigvalsh(gram)
         feats.append(jnp.sqrt(jnp.clip(jnp.sort(eig)[-2], 0.0, None)))
 
     return jnp.stack([feats[i] for i in select])

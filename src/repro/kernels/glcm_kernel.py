@@ -1,56 +1,69 @@
 """Pallas TPU kernels for GLCM voting — the paper's contribution, TPU-native.
 
-Two kernels:
+Every kernel votes with the same **row-wise one-hot MXU matmul**. A row of
+W pixels is kept as it sits in the vector registers: pixels on the 128-wide
+lane axis, one row per sublane. Its associate levels ``a`` and reference
+levels ``r`` (both (1, W) int32) become lane-major one-hots
+``A, R ∈ {0,1}^(L×W)`` (levels on sublanes, built by an iota compare on the
+VPU), and the row's sub-GLCM is ``R Aᵀ`` — an int8 matmul contracting over
+the lanes, accumulated in int32 on the MXU. The CUDA atomicAdd of the
+paper's Scheme 1 is thereby replaced by a reduction along the systolic axis:
+many pairs voting one bin is a sum, not a serialized conflict. No tile is
+ever flattened into a 1-D pair stream (Mosaic's (8, 128) vector layout has
+no cheap 2-D → 1-D relayout); rows are read straight from the VMEM blocks.
 
-``glcm_vote_kernel``  — the workhorse. Votes a flat pair stream
-    (assoc, ref) into an (L, L) co-occurrence matrix. The CUDA atomicAdd of
-    Scheme 1 is replaced by a **one-hot MXU matmul**: a chunk of P pairs
-    becomes one-hot matrices R, A ∈ {0,1}^(P×L) and the chunk's sub-GLCM is
-    RᵀA — the "conflict" (many pairs voting one bin) becomes a reduction
-    along the systolic axis, performed in hardware with no serialization.
-    The paper's R copies (Scheme 2, Eq. (5)/(6)) appear as ``copies``
-    sub-accumulators per chunk: the pair stream is split R ways, each
-    sub-stream gets a private (L, L) accumulator (VMEM — the shared-memory
-    analogue), summed before leaving the kernel.
+A level of -1 is the **dead vote**: its one-hot column is all zero, so the
+pair is dropped. Every out-of-image, out-of-window or padded position is
+masked to -1 instead of being sliced away, which keeps every vector at its
+full, tile-aligned width.
 
-``glcm_fused_kernel`` — beyond-paper fusion for images whose full width fits
-    VMEM: one pass over the image computes GLCMs for MULTIPLE (d, θ) offsets
-    simultaneously (the associate one-hot is built once and reused), with the
-    halo of paper Eq. (8)/(9) realized as a second input Ref whose
-    ``index_map`` points at the *next* row tile. The Pallas grid pipeline
-    double-buffers the HBM→VMEM tile DMA against compute — exactly the
-    two-stream timeline of paper Fig. 3, but structural.
+The level axis of the one-hots and of the (L, L) accumulators is padded to
+a multiple of 8 (the int32 sublane tile); the wrappers slice the padding
+off. Rows wider than ``_ONEHOT_ELEMS / L_pad`` lanes are voted in lane
+chunks so the one-hot intermediates stay a few hundred KiB of VMEM.
 
-``glcm_window_kernel`` — the region-structured workload (sliding-window /
-    tiled texture maps): the input is the extracted (B, gh, gw, rh, rw)
-    patch grid and the **window grid rides the kernel grid axes** — grid =
-    (B, gh, gw), one grid cell per window, each voting its patch's
-    multi-offset GLCM into its own output block (no cross-step accumulation:
-    windows are independent, so the grid is embarrassingly parallel and the
-    HBM→VMEM patch DMA double-buffers against the previous window's voting
-    matmuls). This is the paper's image-partitioning idea promoted from an
-    internal blocking trick to the unit of output.
+``copies`` is the paper's R (Scheme 2, Eq. (5)/(6)): each grid step's rows
+are split into R contiguous sub-streams, each voting into a private (L, L)
+accumulator, summed before leaving the kernel.
 
-``glcm_volume_kernel`` (``_volume_kernel``) — the volumetric workload: a
-    (B, D, H, W) stack of 3-D volumes is processed as a grid over
-    ``(B, n_slabs)`` **depth slabs**, each slab voting all 13 unique 3-D
-    directions at once with the paper's R-copy privatized accumulators.
-    The inter-slice halo (dz > 0 directions) is the NEXT slab, DMA'd via a
-    second input Ref exactly like the fused kernel's next-row-tile — the
-    image-partitioning strategy promoted to the depth axis of a volume.
+Four kernels:
 
-The accumulating kernels carry a **batch grid axis**: the grid is (B, steps) and the
-output block index_map pins each image's accumulator to its batch slot, so a
-(B, H, W) stack is processed in ONE ``pallas_call`` launch instead of B —
-the launch-amortization that dominates serving throughput (see
-``benchmarks/batch_throughput.py``). Grid iteration on TPU is sequential per
-core with the LAST axis innermost, so for a fixed batch slot the constant
-``index_map`` output block acts as a revisited accumulator: it is zeroed at
-step 0 of that image and incremented by every subsequent grid step.
-Single-image (2-D) inputs are handled as B=1 and squeezed on exit.
+``glcm_vote_pallas`` — pair-stream voting. Pre-formed (assoc, ref) streams
+    are laid out as (B, rows, chunk/8) so each grid step holds an (8, chunk/8)
+    block of each stream (a legal (8, 128)-or-full-dim block).
 
-Accumulation is int32 (one-hot int8 matmuls with ``preferred_element_type=
-int32``) so counts are exact up to 2³¹ — f32 accumulation would silently
+``glcm_fused_pallas`` — beyond-paper fusion for whole images: one pass over
+    the image computes GLCMs for MULTIPLE (dy, dx) offsets, reusing each row's
+    associate one-hot across offsets. The halo of paper Eq. (8)/(9) is a
+    second input Ref whose ``index_map`` points at the *next* row tile; the
+    Pallas grid pipeline double-buffers the HBM→VMEM tile DMA against the
+    voting — the two-stream timeline of paper Fig. 3, made structural.
+    Column offsets are lane rotations (``pltpu.roll``) plus an edge mask.
+
+``glcm_window_pallas`` — region-structured voting: the extracted
+    (B, gh, gw, rh, rw) patch grid rides the kernel grid, one grid cell per
+    window, each voting its patch's multi-offset GLCM into its own output
+    block (windows are independent, so no cross-step accumulation).
+
+``glcm_volume_pallas`` — volumes: a (B, D, H, W) stack is processed as a
+    grid over ``(B, n_slabs)`` depth slabs, each voting all requested 3-D
+    directions at once; the inter-slice halo (dz > 0) is the NEXT slab,
+    DMA'd via a second input Ref exactly like the fused kernel's next tile.
+
+The accumulating kernels carry a **batch grid axis**: the grid is
+(B, steps) and the output ``index_map`` pins each image's accumulator to its
+batch slot, so a stack is processed in ONE ``pallas_call`` launch. Grid
+iteration is sequential with the LAST axis innermost, so the constant
+output block acts as a revisited accumulator: zeroed at step 0 of that
+image and incremented by every later step.
+
+With ``quant=(lo, span)`` the fused, window and volume kernels take RAW
+values and bin each row in-register (the same f32 op sequence as
+``core.quantize.bin_values``); the per-image (lo, span) table lives in SMEM
+and is indexed by the batch grid position.
+
+Accumulation is int32 (int8 one-hot matmuls with ``preferred_element_type=
+int32``), so counts are exact up to 2³¹ — f32 accumulation would silently
 round past 2²⁴ on gigapixel images.
 """
 
@@ -61,6 +74,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "glcm_vote_pallas",
@@ -72,77 +86,121 @@ __all__ = [
     "DEFAULT_SLAB_D",
 ]
 
-DEFAULT_CHUNK = 2048   # pair-stream chunk per grid step (multiple of 128)
+DEFAULT_CHUNK = 2048   # pairs per grid step of the pair-stream kernel
 DEFAULT_COPIES = 4     # R, the paper's copy count
 DEFAULT_SLAB_D = 8     # depth slices per slab of the volume kernel
 
+_LEVEL_TILE = 8               # int32 sublane tile: level-axis padding
+_ONEHOT_ELEMS = 64 * 1024     # cap on L_pad × lane-chunk per one-hot
+_STREAM_ROWS = 8              # sublane rows per pair-stream block
 
-def _bin_tile(x: jax.Array, levels: int, lo, span) -> jax.Array:
-    """In-register uniform binning of a raw f32 tile — the same op sequence
-    as ``core.quantize.bin_values`` (f32 affine, floor, clip, int32 cast), so
-    fused-quantize kernel plans are bit-exact with quantize-then-count."""
+
+def _padded_levels(levels: int) -> int:
+    """The one-hot / accumulator level extent: ``levels`` rounded up to 8."""
+    return -(-levels // _LEVEL_TILE) * _LEVEL_TILE
+
+
+def _lane_chunk(width: int, lp: int) -> int:
+    """Lanes voted per one-hot: the whole row, or the largest multiple of 128
+    dividing ``width`` that keeps an (lp, chunk) one-hot under the cap."""
+    if width % 128:
+        return width
+    cw = max(128, min(width, _ONEHOT_ELEMS // lp) // 128 * 128)
+    while width % cw:
+        cw -= 128
+    return cw
+
+
+def _bin(x: jax.Array, levels: int, quant) -> jax.Array:
+    """Raw row → int32 levels with the same op sequence as
+    ``core.quantize.bin_values`` (f32 affine, floor, clip, int32 cast), so
+    fused-quantize kernel plans are bit-exact with quantize-then-count;
+    ``quant=None`` means the row already holds levels."""
+    if quant is None:
+        return x
+    lo, span = quant
     q = jnp.floor((x.astype(jnp.float32) - lo) / span * levels)
     return jnp.clip(q, 0, levels - 1).astype(jnp.int32)
 
 
-def _quant_block(quant, b: int) -> jax.Array:
+def _quant_table(quant, b: int) -> jax.Array:
     """Normalize a (lo, span) pair — python floats or per-image (B,) arrays —
-    into the (B, 2) f32 operand the kernels index by the batch grid axis."""
+    into the (B, 2) f32 SMEM table the kernels index by batch position."""
     lo = jnp.broadcast_to(jnp.asarray(quant[0], jnp.float32).reshape(-1), (b,))
     span = jnp.broadcast_to(jnp.asarray(quant[1], jnp.float32).reshape(-1), (b,))
     return jnp.stack([lo, span], axis=1)
 
 
-def _onehot2d(v: jax.Array, levels: int, dtype=jnp.int8) -> jax.Array:
-    """(P,) int32 → (P, L) one-hot. Built by iota-compare on the VPU; values
-    of -1 (padding / masked votes) yield an all-zero row, dropping the vote."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (v.shape[0], levels), 1)
-    return (v[:, None] == iota).astype(dtype)
+def _quant_spec() -> pl.BlockSpec:
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _vote_matmul(r: jax.Array, a: jax.Array, levels: int, copies: int) -> jax.Array:
-    """Conflict-free voting of a pair chunk: Σ_ρ R_ρᵀ A_ρ over ``copies``
-    private sub-accumulators (int32)."""
-    chunk = r.shape[0]
-    assert chunk % copies == 0, (chunk, copies)
-    sub = chunk // copies
-    acc = jnp.zeros((levels, levels), jnp.int32)
-    for c in range(copies):  # static unroll: R independent MXU matmuls
-        rs = jax.lax.dynamic_slice_in_dim(r, c * sub, sub)
-        as_ = jax.lax.dynamic_slice_in_dim(a, c * sub, sub)
-        R = _onehot2d(rs, levels)
-        A = _onehot2d(as_, levels)
-        acc = acc + jax.lax.dot_general(
-            R, A, (((0,), (0,)), ((), ())), preferred_element_type=jnp.int32
-        )
-    return acc
+def _shift_mask(row: jax.Array, dx: int, ok, width: int) -> jax.Array:
+    """The reference row seen from each associate column: ``row[x + dx]``,
+    or -1 (dead vote) where ``x + dx`` leaves [0, width) or ``ok`` is
+    False. The shift is a lane rotation, skipped when dx == 0."""
+    if dx % row.shape[1]:
+        row = pltpu.roll(row, (-dx) % row.shape[1], 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    valid = jnp.logical_and(ok, (col + dx >= 0) & (col + dx < width))
+    return jnp.where(valid, row, -1)
 
 
-def _vote_stream(r: jax.Array, a: jax.Array, levels: int, copies: int) -> jax.Array:
-    """``_vote_matmul`` for a stream whose length need not divide ``copies``:
-    pads both streams with dead votes (-1 → all-zero one-hot rows) first.
-    The pad length is shape-derived, so it stays static under tracing."""
-    pad = (-r.shape[0]) % copies
-    if pad:
-        r = jnp.concatenate([r, jnp.full((pad,), -1, jnp.int32)])
-        a = jnp.concatenate([a, jnp.full((pad,), -1, jnp.int32)])
-    return _vote_matmul(r, a, levels, copies)
+def _onehot(v: jax.Array, lp: int) -> jax.Array:
+    """(1, W) int32 levels → (lp, W) int8 lane-major one-hot; -1 → zero
+    column (the dead vote)."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (lp, v.shape[1]), 0)
+    return (iota == v).astype(jnp.int8)
+
+
+def _vote_row(a: jax.Array, rs, lp: int, cw: int, acc):
+    """Add one row's votes: associate row ``a`` against each reference row
+    of ``rs`` (all (1, W) int32) into the matching (lp, lp) accumulator of
+    ``acc``. The associate one-hot is built once per lane chunk and shared
+    by every offset."""
+    acc = list(acc)
+    for c0 in range(0, a.shape[1], cw):
+        oa = _onehot(a[:, c0 : c0 + cw], lp)
+        for k, r in enumerate(rs):
+            orf = _onehot(r[:, c0 : c0 + cw], lp)
+            acc[k] = acc[k] + jax.lax.dot_general(
+                orf, oa, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.int32,
+            )
+    return tuple(acc)
+
+
+def _accumulate(n_rows: int, copies: int, row_fn, n_out: int, lp: int):
+    """Σ_y row_fn over rows [0, n_rows), split into ``copies`` contiguous
+    sub-streams with private accumulators (paper Eq. (5)/(6)), summed."""
+    zero = tuple(jnp.zeros((lp, lp), jnp.int32) for _ in range(n_out))
+    total = zero
+    for c in range(copies):
+        lo, hi = n_rows * c // copies, n_rows * (c + 1) // copies
+        if hi > lo:
+            part = jax.lax.fori_loop(lo, hi, row_fn, zero)
+            total = tuple(t + p for t, p in zip(total, part))
+    return total
 
 
 # ---------------------------------------------------------------------------
 # Kernel 1: pair-stream voting (grid = (B, steps))
 # ---------------------------------------------------------------------------
 
-def _vote_kernel(a_ref, r_ref, o_ref, *, levels: int, copies: int):
+def _vote_kernel(a_ref, r_ref, o_ref, *, lp: int, copies: int, cw: int):
     # Steps are the innermost grid axis: step 0 of each image zeroes that
     # image's accumulator block before any votes land in it.
     @pl.when(pl.program_id(1) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = a_ref[...].reshape(-1)
-    r = r_ref[...].reshape(-1)
-    o_ref[0, :, :] += _vote_matmul(r, a, levels, copies)
+    def row_fn(y, acc):
+        return _vote_row(
+            a_ref[0, pl.ds(y, 1), :], (r_ref[0, pl.ds(y, 1), :],), lp, cw, acc
+        )
+
+    (acc,) = _accumulate(a_ref.shape[1], copies, row_fn, 1, lp)
+    o_ref[0, :, :] += acc
 
 
 @functools.partial(
@@ -162,8 +220,8 @@ def glcm_vote_pallas(
     Inputs are int32 of equal shape — either 1-D ``(N,)`` (one stream →
     ``(L, L)``) or 2-D ``(B, N)`` (one stream per image → ``(B, L, L)``,
     computed in a single kernel launch over a ``(B, steps)`` grid). Entries
-    of -1 are padding and do not vote. Streams are padded to a chunk
-    multiple internally.
+    of -1 are padding and do not vote. Each grid step votes an
+    (8, ⌈chunk/8⌉) block of each stream; streams are padded to whole steps.
     """
     if assoc.shape != ref.shape or assoc.ndim not in (1, 2):
         raise ValueError(
@@ -178,24 +236,28 @@ def glcm_vote_pallas(
         a = a[None, :]
         r = r[None, :]
     b, n = a.shape
-    pad = (-n) % chunk
+    lanes = -(-chunk // _STREAM_ROWS)
+    per_step = _STREAM_ROWS * lanes
+    pad = (-n) % per_step if n else per_step
     a = jnp.pad(a, ((0, 0), (0, pad)), constant_values=-1)
     r = jnp.pad(r, ((0, 0), (0, pad)), constant_values=-1)
-    steps = a.shape[1] // chunk
-    a = a.reshape(b, steps, chunk)
-    r = r.reshape(b, steps, chunk)
+    steps = a.shape[1] // per_step
+    a = a.reshape(b, steps * _STREAM_ROWS, lanes)
+    r = r.reshape(b, steps * _STREAM_ROWS, lanes)
+    lp = _padded_levels(levels)
 
+    block = pl.BlockSpec((1, _STREAM_ROWS, lanes), lambda bi, i: (bi, i, 0))
     out = pl.pallas_call(
-        functools.partial(_vote_kernel, levels=levels, copies=copies),
+        functools.partial(
+            _vote_kernel, lp=lp, copies=copies, cw=_lane_chunk(lanes, lp)
+        ),
         grid=(b, steps),
-        in_specs=[
-            pl.BlockSpec((1, 1, chunk), lambda bi, i: (bi, i, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bi, i: (bi, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, levels, levels), lambda bi, i: (bi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, levels, levels), jnp.int32),
+        in_specs=[block, block],
+        out_specs=pl.BlockSpec((1, lp, lp), lambda bi, i: (bi, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, lp, lp), jnp.int32),
         interpret=interpret,
     )(a, r)
+    out = out[:, :levels, :levels]
     return out if batched else out[0]
 
 
@@ -207,53 +269,152 @@ def glcm_vote_pallas(
 def _fused_kernel(
     *refs,
     levels: int,
+    lp: int,
     copies: int,
     offsets: tuple[tuple[int, int], ...],
     tile_h: int,
     width: int,
     height: int,
+    cw: int,
     fused_quant: bool = False,
 ):
     # refs is (cur, nxt, o) for pre-quantized input, or (cur, nxt, q, o)
-    # when quantization is fused: q is this image's (1, 2) = (lo, span)
-    # block and the raw f32 tiles are binned IN-REGISTER — the quantized
-    # image never exists in HBM.
+    # when quantization is fused: q is the (B, 2) = (lo, span) SMEM table
+    # and the raw f32 rows are binned IN-REGISTER — the quantized image
+    # never exists in HBM.
     cur_ref, nxt_ref, o_ref = refs[0], refs[1], refs[-1]
-    pid = pl.program_id(1)  # row-tile step within the current image
+    step = pl.program_id(1)  # row-tile step within the current image
 
-    @pl.when(pid == 0)
+    @pl.when(step == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    cur = cur_ref[...].reshape(tile_h, width)
-    nxt = nxt_ref[...].reshape(tile_h, width)
+    quant = None
     if fused_quant:
-        q_ref = refs[2]
-        lo, span = q_ref[0, 0], q_ref[0, 1]
-        cur = _bin_tile(cur, levels, lo, span)
-        nxt = _bin_tile(nxt, levels, lo, span)
-    both = jnp.concatenate([cur, nxt], axis=0)  # (2*TH, W): tile + halo rows
+        bi = pl.program_id(0)
+        quant = (refs[2][bi, 0], refs[2][bi, 1])
+    row0 = step * tile_h  # global row of this tile's first row
 
-    # Global row index of each tile row (for bottom-of-image masking).
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_h, width), 0)
-    col_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_h, width), 1)
-    grow = pid * tile_h + row_iota
+    def load(ref, y):
+        return ref[0, pl.ds(y, 1), :]
 
-    # Associate one-hot: built ONCE, shared by every offset (the fusion win).
-    a_flat = jnp.where(grow < height, cur, -1).reshape(-1)
+    def row_fn(y, acc):
+        a = _shift_mask(_bin(load(cur_ref, y), levels, quant), 0,
+                        row0 + y < height, width)
+        rs = []
+        for dy, dx in offsets:  # static unroll over directions
+            # Reference row y + dy: in this tile, or in the halo tile.
+            yy = y + dy
+            if dy == 0:
+                raw = load(cur_ref, y)
+            elif dy == tile_h:
+                raw = load(nxt_ref, y)
+            else:
+                raw = jnp.where(
+                    yy < tile_h,
+                    load(cur_ref, jnp.minimum(yy, tile_h - 1)),
+                    load(nxt_ref, jnp.maximum(yy - tile_h, 0)),
+                )
+            rs.append(_shift_mask(_bin(raw, levels, quant), dx,
+                                  row0 + yy < height, width))
+        return _vote_row(a, rs, lp, cw, acc)
 
-    for k, (dy, dx) in enumerate(offsets):  # static unroll over directions
-        # Ref plane: rows shifted by dy (may spill into the halo tile), cols
-        # rolled by dx. Wrapped/out-of-image entries are masked to -1 so
-        # their one-hot row is zero (vote dropped) — paper Eq. (8)/(9)'s Pad
-        # region, expressed as masking instead of overlapped copies.
-        shifted = jax.lax.dynamic_slice(both, (dy, 0), (tile_h, width))
-        shifted = jnp.roll(shifted, -dx, axis=1)
-        col_ok = (col_iota + dx >= 0) & (col_iota + dx < width)
-        row_ok = grow + dy < height
-        r_flat = jnp.where(col_ok & row_ok, shifted, -1).reshape(-1)
-        sub = _vote_matmul(r_flat, a_flat, levels, copies)
-        o_ref[0, k, :, :] += sub
+    acc = _accumulate(tile_h, copies, row_fn, len(offsets), lp)
+    for k in range(len(offsets)):
+        o_ref[0, k, :, :] += acc[k]
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("levels", "offsets", "tile_h", "copies", "interpret"),
+)
+def glcm_fused_pallas(
+    img: jax.Array,
+    *,
+    levels: int,
+    offsets: tuple[tuple[int, int], ...],
+    tile_h: int = 8,
+    copies: int = 1,
+    interpret: bool = False,
+    quant=None,
+) -> jax.Array:
+    """One pass over quantized image(s) → multi-offset GLCMs (int32).
+
+    ``img`` is (H, W) → (n_offsets, L, L), or (B, H, W) → (B, n_offsets,
+    L, L); the batch is the leading grid axis, so all B images are processed
+    by ONE kernel launch with the per-image accumulator selected by the
+    output ``index_map``.
+
+    With ``quant=(lo, span)`` (python floats, or per-image (B,) arrays) the
+    input is RAW values: each f32 row is binned in-register by the same
+    affine as ``core.quantize.bin_values`` before voting, so the quantized
+    image is never materialized. Padded rows are masked by the row index,
+    so raw pad values never vote.
+
+    ``offsets`` are (dy, dx) pixel offsets (see ``kernels.ref.glcm_offsets``);
+    every dy must satisfy 0 <= dy <= tile_h so the halo fits in the next row
+    tile. Image height is padded to a tile multiple (padded rows masked).
+    On TPU ``tile_h`` must be a multiple of 8 (or the padded height). The
+    full image width is kept resident per tile: the VMEM working set is
+    two double-buffered (tile_h, W) tiles plus a few (L_pad, chunk) one-hots
+    — independent of B and H, which only advance the DMA source.
+    """
+    if img.ndim not in (2, 3):
+        raise ValueError(f"expected (H, W) or (B, H, W) image, got {img.shape}")
+    batched = img.ndim == 3
+    h, w = img.shape[-2:]
+    for dy, dx in offsets:
+        if not (0 <= dy <= tile_h):
+            raise ValueError(f"dy={dy} must be in [0, tile_h={tile_h}]")
+        if abs(dx) >= w:
+            raise ValueError(f"|dx|={abs(dx)} must be < width={w}")
+    imgs = img.astype(jnp.float32 if quant is not None else jnp.int32)
+    if not batched:
+        imgs = imgs[None]
+    pad_h = (-h) % tile_h
+    imgp = jnp.pad(imgs, ((0, 0), (0, pad_h), (0, 0)), constant_values=-1)
+    b, hp, _ = imgp.shape
+    steps = hp // tile_h
+    n_off = len(offsets)
+    lp = _padded_levels(levels)
+
+    in_specs = [
+        pl.BlockSpec((1, tile_h, w), lambda bi, i: (bi, i, 0)),
+        # Halo: the NEXT row tile of the SAME image (clamped at the
+        # bottom; the clamp is safe because rows >= height are masked
+        # in-kernel).
+        pl.BlockSpec(
+            (1, tile_h, w), lambda bi, i: (bi, jnp.minimum(i + 1, steps - 1), 0)
+        ),
+    ]
+    args = [imgp, imgp]
+    if quant is not None:
+        # The (B, 2) (lo, span) table — the ONLY quantization state a fused
+        # plan materializes — sits whole in SMEM.
+        in_specs.append(_quant_spec())
+        args.append(_quant_table(quant, b))
+
+    out = pl.pallas_call(
+        functools.partial(
+            _fused_kernel,
+            levels=levels,
+            lp=lp,
+            copies=copies,
+            offsets=tuple(offsets),
+            tile_h=tile_h,
+            width=w,
+            height=h,
+            cw=_lane_chunk(w, lp),
+            fused_quant=quant is not None,
+        ),
+        grid=(b, steps),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, n_off, lp, lp), lambda bi, i: (bi, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, n_off, lp, lp), jnp.int32),
+        interpret=interpret,
+    )(*args)
+    out = out[..., :levels, :levels]
+    return out if batched else out[0]
 
 
 # ---------------------------------------------------------------------------
@@ -263,34 +424,43 @@ def _fused_kernel(
 def _window_kernel(
     *refs,
     levels: int,
+    lp: int,
     copies: int,
     offsets: tuple[tuple[int, int], ...],
     rh: int,
     rw: int,
+    cw: int,
     fused_quant: bool = False,
 ):
     # One grid cell per (batch, window-row, window-col): this cell's patch is
     # in VMEM and its output block is private, so the whole GLCM is produced
     # by straight assignment — no @pl.when init, no revisited accumulator.
-    # refs is (p, o), or (p, q, o) when quantization is fused — q holds the
-    # patch's image-level (lo, span) (windows share their image's range).
+    # refs is (p, o), or (p, q, o) when quantization is fused — q is the
+    # (B, 2) SMEM table of image-level (lo, span) (windows share their
+    # image's range).
     p_ref, o_ref = refs[0], refs[-1]
-    patch = p_ref[...].reshape(rh, rw)
+    quant = None
     if fused_quant:
-        q_ref = refs[1]
-        patch = _bin_tile(patch, levels, q_ref[0, 0], q_ref[0, 1])
-    for k, (dy, dx) in enumerate(offsets):  # static unroll over directions
-        # Intra-window pair planes (paper Eq. (2) addressing, region-local):
+        bi = pl.program_id(0)
+        quant = (refs[1][bi, 0], refs[1][bi, 1])
+
+    def load(y):
+        return _bin(p_ref[0, 0, 0, pl.ds(y, 1), :], levels, quant)
+
+    def row_fn(y, acc):
+        # Intra-window pairs (paper Eq. (2) addressing, region-local):
         # pairs never cross a window boundary, by the workload's definition.
-        if dx >= 0:
-            assoc = patch[: rh - dy, : rw - dx] if dx else patch[: rh - dy, :]
-            ref = patch[dy:, dx:]
-        else:
-            assoc = patch[: rh - dy, -dx:]
-            ref = patch[dy:, : rw + dx]
-        o_ref[0, 0, 0, k, :, :] = _vote_stream(
-            ref.reshape(-1), assoc.reshape(-1), levels, copies
-        )
+        a = load(y)
+        rs = [
+            _shift_mask(load(jnp.minimum(y + dy, rh - 1)) if dy else a, dx,
+                        y + dy < rh, rw)
+            for dy, dx in offsets
+        ]
+        return _vote_row(a, rs, lp, cw, acc)
+
+    acc = _accumulate(rh, copies, row_fn, len(offsets), lp)
+    for k in range(len(offsets)):
+        o_ref[0, 0, 0, k, :, :] = acc[k]
 
 
 @functools.partial(
@@ -332,34 +502,38 @@ def glcm_window_pallas(
                 f"offset (dy={dy}, dx={dx}) does not fit region ({rh}, {rw})"
             )
     n_off = len(offsets)
+    lp = _padded_levels(levels)
 
     in_specs = [
         pl.BlockSpec((1, 1, 1, rh, rw), lambda bi, i, j: (bi, i, j, 0, 0)),
     ]
     args = [p]
     if quant is not None:
-        in_specs.append(pl.BlockSpec((1, 2), lambda bi, i, j: (bi, 0)))
-        args.append(_quant_block(quant, b))
+        in_specs.append(_quant_spec())
+        args.append(_quant_table(quant, b))
 
     out = pl.pallas_call(
         functools.partial(
             _window_kernel,
             levels=levels,
+            lp=lp,
             copies=copies,
             offsets=tuple(offsets),
             rh=rh,
             rw=rw,
+            cw=_lane_chunk(rw, lp),
             fused_quant=quant is not None,
         ),
         grid=(b, gh, gw),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, 1, n_off, levels, levels),
+            (1, 1, 1, n_off, lp, lp),
             lambda bi, i, j: (bi, i, j, 0, 0, 0),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, gh, gw, n_off, levels, levels), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((b, gh, gw, n_off, lp, lp), jnp.int32),
         interpret=interpret,
     )(*args)
+    out = out[..., :levels, :levels]
     return out if batched else out[0]
 
 
@@ -371,66 +545,64 @@ def glcm_window_pallas(
 def _volume_kernel(
     *refs,
     levels: int,
+    lp: int,
     copies: int,
     offsets: tuple[tuple[int, int, int], ...],
     slab_d: int,
     height: int,
     width: int,
     depth: int,
+    cw: int,
     has_halo: bool = True,
     fused_quant: bool = False,
 ):
     # refs is (cur, [nxt,] [q,] o): the next-slab halo block when any offset
     # has dz > 0 (skipped otherwise — half the HBM→VMEM traffic), and the
-    # (1, 2) = (lo, span) block when quantization is fused (raw f32 slabs
-    # binned in-register; the quantized volume never exists in HBM).
+    # (B, 2) = (lo, span) SMEM table when quantization is fused (raw f32
+    # rows binned in-register; the quantized volume never exists in HBM).
     cur_ref, o_ref = refs[0], refs[-1]
-    pid = pl.program_id(1)  # depth-slab step within the current volume
+    nxt_ref = refs[1] if has_halo else None
+    step = pl.program_id(1)  # depth-slab step within the current volume
 
-    @pl.when(pid == 0)
+    @pl.when(step == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    cur = cur_ref[...].reshape(slab_d, height, width)
-    nxt = (
-        refs[1][...].reshape(slab_d, height, width) if has_halo else None
-    )
+    quant = None
     if fused_quant:
-        q_ref = refs[-2]
-        lo, span = q_ref[0, 0], q_ref[0, 1]
-        cur = _bin_tile(cur, levels, lo, span)
-        if has_halo:
-            nxt = _bin_tile(nxt, levels, lo, span)
-    if has_halo:
-        both = jnp.concatenate([cur, nxt], axis=0)  # (2·SD, H, W): slab+halo
-    else:
-        both = cur  # dz == 0 everywhere: dynamic_slice never leaves the slab
+        bi = pl.program_id(0)
+        quant = (refs[-2][bi, 0], refs[-2][bi, 1])
+    z0 = step * slab_d  # global depth of this slab's first slice
 
-    z_iota = jax.lax.broadcasted_iota(jnp.int32, (slab_d, height, width), 0)
-    y_iota = jax.lax.broadcasted_iota(jnp.int32, (slab_d, height, width), 1)
-    x_iota = jax.lax.broadcasted_iota(jnp.int32, (slab_d, height, width), 2)
-    gz = pid * slab_d + z_iota  # global depth of each slab voxel
+    def load(ref, z, y):
+        return ref[0, z, pl.ds(y, 1), :]
 
-    # Associate one-hot source: built ONCE, shared by every direction (the
-    # fusion win, exactly as in the 2-D fused kernel); depth-padded voxels
-    # (gz >= depth) are masked to the dead bin.
-    a_flat = jnp.where(gz < depth, cur, -1).reshape(-1)
+    def row_fn(i, acc):
+        z, y = i // height, i % height
+        a = _shift_mask(_bin(load(cur_ref, z, y), levels, quant), 0,
+                        z0 + z < depth, width)
+        rs = []
+        for dz, dy, dx in offsets:  # static unroll over directions
+            # Reference row: depth z + dz (this slab or the halo slab), row
+            # y + dy (dy may be NEGATIVE for the dz=+1 directions), lanes
+            # rotated by dx; out-of-volume positions are dead votes.
+            zz, yy = z + dz, y + dy
+            yc = jnp.clip(yy, 0, height - 1)
+            if dz == 0:
+                raw = load(cur_ref, z, yc)
+            else:
+                raw = jnp.where(
+                    zz < slab_d,
+                    load(cur_ref, jnp.minimum(zz, slab_d - 1), yc),
+                    load(nxt_ref, jnp.maximum(zz - slab_d, 0), yc),
+                )
+            ok = (z0 + zz < depth) & (yy >= 0) & (yy < height)
+            rs.append(_shift_mask(_bin(raw, levels, quant), dx, ok, width))
+        return _vote_row(a, rs, lp, cw, acc)
 
-    for k, (dz, dy, dx) in enumerate(offsets):  # static unroll, 13 directions
-        # Ref plane: depth shifted by dz (may spill into the halo slab), rows
-        # and cols rolled in-plane by (dy, dx) — dy may be NEGATIVE for the
-        # dz=+1 directions, which the roll+mask handles symmetrically.
-        # Wrapped/out-of-volume entries are masked to -1 (vote dropped) —
-        # paper Eq. (8)/(9)'s Pad region as masking instead of copies.
-        shifted = jax.lax.dynamic_slice(both, (dz, 0, 0), (slab_d, height, width))
-        shifted = jnp.roll(shifted, (-dy, -dx), axis=(1, 2))
-        ok = (
-            (gz + dz < depth)
-            & (y_iota + dy >= 0) & (y_iota + dy < height)
-            & (x_iota + dx >= 0) & (x_iota + dx < width)
-        )
-        r_flat = jnp.where(ok, shifted, -1).reshape(-1)
-        o_ref[0, k, :, :] += _vote_stream(r_flat, a_flat, levels, copies)
+    acc = _accumulate(slab_d * height, copies, row_fn, len(offsets), lp)
+    for k in range(len(offsets)):
+        o_ref[0, k, :, :] += acc[k]
 
 
 @functools.partial(
@@ -461,11 +633,13 @@ def glcm_volume_pallas(
     slab transfer against the previous slab's voting matmuls — the paper's
     two-stream timeline along the depth axis. ``offsets`` are (dz, dy, dx)
     voxel offsets with 0 <= dz <= slab_d (the halo reach); dy/dx may be
-    negative (rolled + masked in-plane). ``copies`` is the paper's R:
-    private (L, L) sub-accumulators per slab, summed before leaving the
-    kernel. Depth is padded to a slab multiple (padded slices masked). The
-    VMEM working set is 2·slab_d·H·W·4B (slabs) + the one-hot chunk —
-    independent of B and D, which only advance the DMA source.
+    negative (masked in-plane). ``copies`` is the paper's R: private
+    (L, L) sub-accumulators per slab, summed before leaving the kernel.
+    Depth is padded to a slab multiple (padded slices masked). The VMEM
+    working set is 2 inputs × 2 buffers × slab_d·H·W·4B plus a few
+    (L_pad, chunk) one-hots — independent of B and D, which only advance
+    the DMA source (``kernels.ops.glcm_pallas_volume`` sizes ``slab_d`` so
+    it fits).
     """
     if vol.ndim not in (3, 4):
         raise ValueError(
@@ -488,6 +662,7 @@ def glcm_volume_pallas(
     b, dp, _, _ = volp.shape
     steps = dp // slab_d
     n_off = len(offsets)
+    lp = _padded_levels(levels)
 
     in_specs = [pl.BlockSpec((1, slab_d, h, w), lambda bi, i: (bi, i, 0, 0))]
     args = [volp]
@@ -505,119 +680,29 @@ def glcm_volume_pallas(
         )
         args.append(volp)
     if quant is not None:
-        in_specs.append(pl.BlockSpec((1, 2), lambda bi, i: (bi, 0)))
-        args.append(_quant_block(quant, b))
+        in_specs.append(_quant_spec())
+        args.append(_quant_table(quant, b))
 
     out = pl.pallas_call(
         functools.partial(
             _volume_kernel,
             levels=levels,
+            lp=lp,
             copies=copies,
             offsets=tuple(offsets),
             slab_d=slab_d,
             height=h,
             width=w,
             depth=d,
+            cw=_lane_chunk(w, lp),
             has_halo=has_halo,
             fused_quant=quant is not None,
         ),
         grid=(b, steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, n_off, levels, levels), lambda bi, i: (bi, 0, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, n_off, levels, levels), jnp.int32),
+        out_specs=pl.BlockSpec((1, n_off, lp, lp), lambda bi, i: (bi, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, n_off, lp, lp), jnp.int32),
         interpret=interpret,
     )(*args)
-    return out if batched else out[0]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("levels", "offsets", "tile_h", "copies", "interpret"),
-)
-def glcm_fused_pallas(
-    img: jax.Array,
-    *,
-    levels: int,
-    offsets: tuple[tuple[int, int], ...],
-    tile_h: int = 8,
-    copies: int = 1,
-    interpret: bool = False,
-    quant=None,
-) -> jax.Array:
-    """One pass over quantized image(s) → multi-offset GLCMs (int32).
-
-    ``img`` is (H, W) → (n_offsets, L, L), or (B, H, W) → (B, n_offsets,
-    L, L); the batch is the leading grid axis, so all B images are processed
-    by ONE kernel launch with the per-image accumulator selected by the
-    output ``index_map``.
-
-    With ``quant=(lo, span)`` (python floats, or per-image (B,) arrays) the
-    input is RAW values: each f32 tile is binned in-register by the same
-    affine as ``core.quantize.bin_values`` before voting, so the quantized
-    image is never materialized. Padded rows are masked by the row iota, so
-    raw pad values never vote.
-
-    ``offsets`` are (dy, dx) pixel offsets (see ``kernels.ref.glcm_offsets``);
-    every dy must satisfy 0 <= dy <= tile_h so the halo fits in the next row
-    tile. Image height is padded to a tile multiple (padded rows masked).
-    The full image width is kept resident per tile: the VMEM working set is
-    2·tile_h·W·4B (tiles) + tile_h·W·L·1B (one-hot) + n_off·L²·4B — callers
-    should keep ``tile_h * W ≲ 256K`` elements (independent of B: the batch
-    axis only advances the DMA source, never the working set).
-    """
-    if img.ndim not in (2, 3):
-        raise ValueError(f"expected (H, W) or (B, H, W) image, got {img.shape}")
-    batched = img.ndim == 3
-    h, w = img.shape[-2:]
-    for dy, dx in offsets:
-        if not (0 <= dy <= tile_h):
-            raise ValueError(f"dy={dy} must be in [0, tile_h={tile_h}]")
-        if abs(dx) >= w:
-            raise ValueError(f"|dx|={abs(dx)} must be < width={w}")
-    imgs = img.astype(jnp.float32 if quant is not None else jnp.int32)
-    if not batched:
-        imgs = imgs[None]
-    pad_h = (-h) % tile_h
-    imgp = jnp.pad(imgs, ((0, 0), (0, pad_h), (0, 0)), constant_values=-1)
-    b, hp, _ = imgp.shape
-    steps = hp // tile_h
-    n_off = len(offsets)
-
-    in_specs = [
-        pl.BlockSpec((1, tile_h, w), lambda bi, i: (bi, i, 0)),
-        # Halo: the NEXT row tile of the SAME image (clamped at the
-        # bottom; the clamp is safe because rows >= height are masked
-        # in-kernel).
-        pl.BlockSpec(
-            (1, tile_h, w), lambda bi, i: (bi, jnp.minimum(i + 1, steps - 1), 0)
-        ),
-    ]
-    args = [imgp, imgp]
-    if quant is not None:
-        # This image's (lo, span): a two-scalar block selected by the batch
-        # grid axis — the ONLY quantization state a fused plan materializes.
-        in_specs.append(pl.BlockSpec((1, 2), lambda bi, i: (bi, 0)))
-        args.append(_quant_block(quant, b))
-
-    out = pl.pallas_call(
-        functools.partial(
-            _fused_kernel,
-            levels=levels,
-            copies=copies,
-            offsets=tuple(offsets),
-            tile_h=tile_h,
-            width=w,
-            height=h,
-            fused_quant=quant is not None,
-        ),
-        grid=(b, steps),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, n_off, levels, levels), lambda bi, i: (bi, 0, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, n_off, levels, levels), jnp.int32),
-        interpret=interpret,
-    )(*args)
+    out = out[..., :levels, :levels]
     return out if batched else out[0]

@@ -40,6 +40,10 @@ __all__ = [
     "should_interpret",
 ]
 
+# VMEM budget of the volume kernel's slab + halo input blocks (both double-
+# buffered); half of v5e's default 16 MiB scoped VMEM limit.
+_SLAB_VMEM_BYTES = 8 * 1024 * 1024
+
 
 def should_interpret(interpret: bool | None = None) -> bool:
     """Pallas interpret mode: forced value, else True iff not running on TPU."""
@@ -160,13 +164,17 @@ def glcm_pallas_volume(
     offsets instead. ``vol`` is (D, H, W) → (len(pairs), L, L) int32, or a
     (B, D, H, W) stack → (B, len(pairs), L, L) — the batch rides the
     kernel's leading grid axis, so the whole stack is one launch.
-    ``slab_d`` defaults to max(8, largest dz) rounded up to 8.
+    ``slab_d`` defaults to 8 depth slices, fewer where the plane is so large
+    that the double-buffered slab and halo blocks would outgrow
+    ``_SLAB_VMEM_BYTES``, and never fewer than the largest dz.
     """
     if offsets is None:
         offsets = tuple(_ref.glcm_offsets_3d(d, k) for d, k in pairs)
     max_dz = max((dz for dz, _, _ in offsets), default=1)
     if slab_d is None:
-        slab_d = max(DEFAULT_SLAB_D, -(-max_dz // 8) * 8)
+        h, w = vol.shape[-2:]
+        fit = max(1, _SLAB_VMEM_BYTES // (16 * h * w))  # 2 refs × 2 buffers × 4 B
+        slab_d = max(max_dz, min(DEFAULT_SLAB_D, fit))
     return glcm_volume_pallas(
         vol,
         levels=levels,

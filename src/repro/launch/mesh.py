@@ -7,44 +7,28 @@ devices via XLA_FLAGS before any jax import (see dryrun.py lines 1-2).
 Single pod : (16, 16)      axes (data, model)   — 256 chips (v5e pod)
 Multi-pod  : (2, 16, 16)   axes (pod, data, model) — 512 chips; the 'pod'
              axis is pure data parallelism (gradient all-reduce crosses DCI).
-
-jax-version compat policy
--------------------------
-This module is the single place mesh construction goes through, and it must
-work across the jax versions we deploy against. ``jax.sharding.AxisType``
-(and the ``axis_types=`` kwarg of ``jax.make_mesh``) only exist in jax
->= 0.5; on older versions (0.4.x, the pinned CI toolchain) every mesh axis
-is implicitly Auto, which is exactly what we request on newer versions — so
-the shim below passes ``axis_types=(AxisType.Auto, ...)`` when available and
-silently omits it otherwise. Do NOT import ``AxisType`` at module top level
-anywhere in this repo; go through :func:`make_compat_mesh`.
 """
 
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-# None on jax < 0.5 — resolved once at import, used to gate the kwarg.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
+from jax.sharding import AxisType, Mesh
 
 
-def make_compat_mesh(shape, axes) -> Mesh:
-    """``jax.make_mesh`` with explicit-Auto axis types where supported."""
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(shape, axes, axis_types=(_AXIS_TYPE.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def _auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with every axis Auto (GSPMD-partitioned)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
     """Small mesh over forced host devices (tests)."""
-    return make_compat_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def required_devices(*, multi_pod: bool = False) -> int:

@@ -54,13 +54,9 @@ def _shape_bytes(shape_str: str) -> int:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions: 0.4.x returns a
-    one-element list of dicts (per partitioned module), newer jax returns
-    the dict directly. Always returns a flat dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``compiled.cost_analysis()`` as a flat dict ({} when XLA reports
+    nothing)."""
+    return compiled.cost_analysis() or {}
 
 
 def collective_bytes(hlo_text: str) -> dict[str, int]:

@@ -492,14 +492,16 @@ class GLCMEngine:
             w.plans[bucket] = plan
         return plan
 
-    def warmup(self, workload: int | None = None) -> None:
+    def warmup(self, workload: int | None = None, *, dtype=np.float32) -> None:
         """Compile AND execute every bucket plan (zero-input) so no live
-        request pays a compile; per workload, or all when None."""
+        request pays a compile; per workload, or all when None.  Programs
+        are specialized on the input dtype, so pass the dtype the requests
+        will carry (e.g. ``np.uint8`` images)."""
         wids = [workload] if workload is not None else list(self._workloads)
         for wid in wids:
             w = self._workload(wid)
             for bucket in w.buckets:
-                stack = np.zeros((bucket, *w.image_shape), np.float32)
+                stack = np.zeros((bucket, *w.image_shape), dtype)
                 np.asarray(self._plan_for(w, bucket)(jnp.asarray(stack)))
 
     # -- request validation ------------------------------------------------

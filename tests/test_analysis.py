@@ -171,7 +171,7 @@ def test_fires_no_f64_promotion(scratch):
         name="_lint_f64", compute=wide, caps=_backends.Capabilities(),
     ))
     spec = GLCMSpec(levels=8, pairs=((1, 0),))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         findings = _lint("_lint_f64", spec, (2, 16, 16))
     assert "no-f64-promotion" in _rules_fired(findings)
 

@@ -394,6 +394,23 @@ def test_warmup_precompiles_every_bucket():
     assert set(eng._workloads[0].plans) == {1, 2, 4}
 
 
+def test_warmup_compiles_for_the_request_dtype():
+    # Programs are specialized on the input dtype: a float32 warm-up leaves
+    # the first uint8 request to compile, a uint8 warm-up does not.
+    spec = GLCMSpec(levels=8, pairs=((1, 0),), quantize="uniform",
+                    scheme="onehot")
+    img = (IMGS[0] * 255).astype(np.uint8)
+    for dtype, compiles in ((np.float32, 1), (np.uint8, 0)):
+        plan_cache_clear()
+        eng = GLCMEngine(GLCMServeConfig(spec=spec, image_shape=SHAPE,
+                                         batch_size=1))
+        eng.warmup(dtype=dtype)
+        fn = eng._workloads[0].plans[1].fn
+        before = fn._cache_size()
+        eng.map([img])
+        assert fn._cache_size() - before == compiles
+
+
 def test_latencies_accessor():
     eng = GLCMEngine(_cfg(batch_size=2))
     eng.map(IMGS[:4])
