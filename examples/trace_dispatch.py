@@ -5,8 +5,8 @@
 One GLCMEngine serves a burst of mixed-spec requests with tracing ON: a
 :class:`~repro.obs.trace.Tracer` is injected into the engine (sharing its
 clock), so every ``submit()`` mints a correlation ID that is carried
-through queue wait → padding → bucket launch → readback, producing one
-span tree per request plus one per dispatched batch.  The trace is saved
+through queue wait → padding → copy → bucket launch → readback, producing
+one span tree per request plus one per dispatched batch.  The trace is saved
 as Chrome ``trace_event`` JSON — open it at https://ui.perfetto.dev or
 ``chrome://tracing`` — and summarized in the terminal with the
 ``repro.obs.report`` helpers (per-phase breakdown, dispatch timeline,

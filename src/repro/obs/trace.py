@@ -6,7 +6,7 @@ into a bounded ring buffer.  Spans come from two sources:
 * ``with tracer.span("name", key=val) as sp:`` — a live, nested context
   manager: the span's parent is whatever span is open on the *same
   thread*, its times come from the tracer's clock, and ``sp.set(k=v)``
-  attaches attributes discovered mid-span.
+  attaches attributes discovered mid-span (to the ring's record only).
 * ``tracer.add_span("name", t0, t1, parent=..., corr=...)`` — a
   retrospective span recorded from explicit timestamps (the serving
   engine measures phase times with its own injected clock anyway, so it
@@ -18,11 +18,19 @@ request's ticket, so a single ``submit()`` is traceable end-to-end as one
 span tree (``repro.obs.report`` groups by it; the Chrome export emits
 correlated spans as async ``b``/``e`` events on a per-request track).
 
+**Live spans on the profiler's clock.**  While a ``jax.profiler`` capture
+runs (``jax.profiler.trace``, ``start_trace``, TensorBoard), every live
+span also opens a ``jax.profiler.TraceAnnotation`` named ``"repro." +
+name`` with its attributes as event stats, enabled tracer or not: the
+capture shows the engine's phases on the host track next to the device
+ops.  The capture itself is the switch (``TraceAnnotation.is_enabled()``).
+Retrospective ``add_span``/``event`` records reach the ring only.
+
 **Disabled is the default and is free.**  ``tracer.span()`` on a disabled
-tracer returns a shared no-op context manager (no allocation beyond the
-kwargs dict, no clock read, no lock); ``tracer.enabled`` is a plain
-attribute so hot paths guard with ``if tr.enabled:``.  The global tracer
-(:func:`get_tracer`) starts disabled unless ``REPRO_TRACE=1`` is set;
+tracer with no capture running returns a shared no-op context manager (no
+allocation beyond the kwargs dict, no clock read, no lock); ``tracer.enabled``
+is a plain attribute so hot paths guard with ``if tr.enabled:``.  The global
+tracer (:func:`get_tracer`) starts disabled unless ``REPRO_TRACE=1`` is set;
 :func:`set_tracer` injects a live one (tests, benchmark ``--trace``).
 
 The clock is injectable (``Tracer(clock=...)``) and must be monotonic;
@@ -81,22 +89,65 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+PROFILER_PREFIX = "repro."
+_annotation_cls = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    importing ``repro.obs`` imports no JAX."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
+
+class _ProfilerSpan:
+    """A disabled tracer's span while a profiler capture runs: the
+    annotation alone, nothing in the ring."""
+
+    __slots__ = ("_name", "_attrs", "_ann")
+
+    def __init__(self, name: str, attrs: dict, corr: object = None):
+        self._name = PROFILER_PREFIX + name
+        self._attrs = attrs if corr is None else {**attrs, "corr": corr}
+
+    def __enter__(self):
+        # a TraceAnnotation's interval starts when it is built
+        self._ann = _annotation()(self._name, **self._attrs)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **attrs):
+        return self
+
 
 class _LiveSpan:
     """Context-manager handle for one open span of an enabled tracer."""
 
-    __slots__ = ("_tr", "name", "attrs", "id", "parent", "t0")
+    __slots__ = ("_tr", "name", "attrs", "corr", "id", "parent", "t0", "_ann")
 
-    def __init__(self, tracer: Tracer, name: str, attrs: dict):
+    def __init__(self, tracer: Tracer, name: str, attrs: dict, corr: object,
+                 capturing: bool):
         self._tr = tracer
         self.name = name
         self.attrs = attrs
+        self.corr = corr
+        self._ann = _ProfilerSpan(name, attrs, corr) if capturing else None
 
     def __enter__(self):
         tr = self._tr
         stack = tr._stack()
         self.parent = stack[-1] if stack else None
         self.id = next(tr._ids)
+        if self._ann is not None:
+            self._ann.__enter__()
         self.t0 = tr.clock()
         stack.append(self.id)
         return self
@@ -104,6 +155,8 @@ class _LiveSpan:
     def __exit__(self, etype, evalue, tb):
         tr = self._tr
         t1 = tr.clock()
+        if self._ann is not None:
+            self._ann.__exit__(etype, evalue, tb)
         stack = tr._stack()
         if self.id in stack:
             # pop through self: un-exited inner ids (generator spans that
@@ -114,7 +167,7 @@ class _LiveSpan:
         tr._append(Span(
             id=self.id, name=self.name, t0=self.t0, t1=t1,
             tid=threading.current_thread().name, parent=self.parent,
-            attrs=self.attrs,
+            corr=self.corr, attrs=self.attrs,
         ))
         return False
 
@@ -162,11 +215,16 @@ class Tracer:
                 self._head = (self._head + 1) % self.capacity
                 self.dropped += 1
 
-    def span(self, name: str, **attrs):
-        """Open a nested span (context manager).  Disabled → shared no-op."""
+    def span(self, name: str, *, corr: object = None, **attrs):
+        """Open a nested span (context manager), also as a profiler
+        annotation while a capture runs.  Disabled with no capture →
+        shared no-op."""
+        capturing = (_annotation_cls or _annotation()).is_enabled()
         if not self.enabled:
-            return _NOOP
-        return _LiveSpan(self, name, attrs)
+            if not capturing:
+                return _NOOP
+            return _ProfilerSpan(name, attrs, corr)
+        return _LiveSpan(self, name, attrs, corr, capturing)
 
     def add_span(self, name: str, t0: float, t1: float, *,
                  parent: int | None = None, corr: object = None,

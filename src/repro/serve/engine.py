@@ -33,23 +33,29 @@ job is to keep launches *full and frequent* under real traffic:
   the shed is counted in ``stats()``.
 * **Observability.**  ``stats()`` reports, per workload: queue depth,
   p50/p95/p99 queue/service/end-to-end latency, a per-phase breakdown
-  (pad / launch / readback — the launch boundary is device-synced via
-  ``block_until_ready``, so device time is real), a batch-occupancy
-  histogram, shed and result-eviction counters — plus the engine-wide
-  plan-cache hit rate.  ``dispatch_log`` keeps the last dispatches for
-  inspection.  Counters/gauges/latency histograms also stream into the
-  process-global :mod:`repro.obs.metrics` registry (Prometheus text via
+  (pad / h2d / launch / readback — the host→device copy and the launch
+  each end in ``block_until_ready``: ``h2d_ms`` runs until the batch is
+  on the device, ``launch_ms`` from there until the output is ready), a
+  batch-occupancy histogram, shed and result-eviction counters — plus
+  the engine-wide plan-cache hit rate.  Counters/gauges/latency
+  histograms also stream into the process-global
+  :mod:`repro.obs.metrics` registry (Prometheus text via
   ``get_registry().to_prometheus()``).
-* **Tracing.**  With a live :class:`repro.obs.trace.Tracer` (inject via
+* **Tracing.**  Each dispatch runs inside live spans: ``glcm.dispatch``
+  around ``glcm.pad`` / ``glcm.h2d`` / ``glcm.launch`` /
+  ``glcm.readback``.  While a ``jax.profiler`` capture runs they appear
+  on its host track as ``repro.glcm.*`` next to the device ops, tracing
+  on or off.  With a live :class:`repro.obs.trace.Tracer` (inject via
   ``GLCMEngine(..., tracer=...)``, install globally with
-  ``set_tracer``, or set ``REPRO_TRACE=1``), every request becomes one
-  span tree under its ticket correlation id — ``glcm.request`` →
-  queue_wait / pad / launch / readback — plus per-batch
-  ``glcm.dispatch`` spans, exportable as Perfetto-loadable Chrome JSON
-  (``tracer.save_chrome``).  Tracing off is a single attribute check on
-  the dispatch path.
+  ``set_tracer``, or set ``REPRO_TRACE=1``) they also reach its ring,
+  and every request becomes one span tree under its ticket correlation
+  id — ``glcm.request`` → queue_wait / pad / h2d / launch / readback —
+  exportable as Perfetto-loadable Chrome JSON (``tracer.save_chrome``).
+  Tracing and capture off, a span is one attribute check and one
+  ``is_enabled()`` call.
 * **Flight recorder.**  ``engine.flight`` keeps a bounded ring of recent
-  dispatch/shed records; on :class:`QueueFullError` or a dispatch
+  dispatch/shed records (bucket, occupancy, tickets, phase ms); on
+  :class:`QueueFullError` or a dispatch
   exception the ring is dumped to ``engine.last_incident`` (and to
   ``REPRO_FLIGHT_DIR`` when set) for post-mortem without tracing on.
 
@@ -280,6 +286,7 @@ class _Workload:
         self.e2e_ms: collections.deque = collections.deque(maxlen=stats_window)
         # per-phase dispatch breakdown (one sample per batch, ms)
         self.pad_ms: collections.deque = collections.deque(maxlen=stats_window)
+        self.h2d_ms: collections.deque = collections.deque(maxlen=stats_window)
         self.launch_ms: collections.deque = collections.deque(maxlen=stats_window)
         self.readback_ms: collections.deque = collections.deque(maxlen=stats_window)
         # cached metrics-registry handles: the dispatch path pays one
@@ -305,7 +312,7 @@ class _Workload:
             phase: reg.histogram(
                 "repro_serve_phase_ms", "dispatch phase latency (ms)",
                 workload=name, phase=phase)
-            for phase in ("queue", "pad", "launch", "readback")
+            for phase in ("queue", "pad", "h2d", "launch", "readback")
         }
 
 
@@ -415,7 +422,6 @@ class GLCMEngine:
         self.batches_dispatched = 0
         self.images_served = 0
         self.frames_streamed = 0
-        self.dispatch_log: collections.deque = collections.deque(maxlen=256)
 
     # -- workload registry -------------------------------------------------
 
@@ -570,19 +576,16 @@ class GLCMEngine:
             raise KeyError(f"stream {stream_id} is unknown or closed")
         frame = self._validate_request(
             frame, kind="frame", want=tuple(self.cfg.image_shape))
-        t0 = self._clock()
-        state, out = self.stream_plan.update(
-            self._streams[stream_id], jnp.asarray(frame)
-        )
-        result = np.asarray(out)
+        with self.tracer.span("glcm.stream_push", corr=f"stream-{stream_id}",
+                              stream=stream_id,
+                              frames_seen=self.frames_streamed + 1):
+            state, out = self.stream_plan.update(
+                self._streams[stream_id], jnp.asarray(frame)
+            )
+            result = np.asarray(out)
         self._streams[stream_id] = state
         self.frames_streamed += 1
         self._m_frames.inc()
-        if self.tracer.enabled:
-            self.tracer.add_span(
-                "glcm.stream_push", t0, self._clock(),
-                corr=f"stream-{stream_id}", stream=stream_id,
-                frames_seen=self.frames_streamed)
         return result
 
     def close_stream(self, stream_id: int):
@@ -759,8 +762,11 @@ class GLCMEngine:
                 "queue_ms": _percentiles(w.queue_ms),
                 "service_ms": _percentiles(w.service_ms),
                 "e2e_ms": _percentiles(w.e2e_ms),
-                # per-phase dispatch breakdown (one sample per batch)
+                # per-phase dispatch breakdown (one sample per batch):
+                # h2d_ms until the batch is on the device, launch_ms from
+                # there until the output is ready
                 "pad_ms": _percentiles(w.pad_ms),
+                "h2d_ms": _percentiles(w.h2d_ms),
                 "launch_ms": _percentiles(w.launch_ms),
                 "readback_ms": _percentiles(w.readback_ms),
             }
@@ -812,56 +818,71 @@ class GLCMEngine:
 
     def _dispatch(self, w: _Workload, n: int, *, now: float,
                   deadline: bool = False) -> None:
-        from repro.core.pipeline import pad_stack
         from repro.core.plan import pick_bucket
 
-        reqs = self._take(w, n, now, deadline)
-        k = len(reqs)
+        k = min(n, len(w.queue))
         bucket = pick_bucket(w.buckets, k)
-        # Phase boundaries (engine clock): pad → launch → readback.  The
-        # launch boundary is a real device sync (block_until_ready), so the
-        # launch/readback split — and any trace span built from it — is
-        # device time, not dispatch-return time; on an async backend
-        # np.asarray would have blocked there anyway, so the untraced path
-        # pays nothing extra.
-        t_pad0 = self._clock()
+        with self.tracer.span("glcm.dispatch", workload=w.name, bucket=bucket,
+                              occupancy=k, deadline=deadline):
+            self._serve(w, self._take(w, n, now, deadline), bucket, deadline)
+
+    def _serve(self, w: _Workload, reqs: list[_Request], bucket: int,
+               deadline: bool) -> None:
+        """Run one taken batch through pad → h2d → launch → readback, store
+        its results and account for it."""
+        from repro.core.pipeline import pad_stack
+
+        k = len(reqs)
+        tr = self.tracer
+        # Phase boundaries (engine clock): pad → h2d → launch → readback.
+        # The copy and the launch each end in a device sync
+        # (block_until_ready), so each phase is device time, not
+        # dispatch-return time.  The kernel is enqueued behind the copy
+        # before the wait on the copy: launched after it instead, the host's
+        # release of the copied batch (~24 ms at 16384², PERF.md) lands on
+        # this thread while the device idles.
         try:
             plan = self._plan_for(w, bucket)
-            stack, _ = pad_stack([r.image for r in reqs], bucket)
-            t_disp = self._clock()
-            out_dev = plan(jnp.asarray(stack))
-            jax.block_until_ready(out_dev)
+            t_pad0 = self._clock()
+            with tr.span("glcm.pad"):
+                stack, _ = pad_stack([r.image for r in reqs], bucket)
+            t_h2d = self._clock()
+            with tr.span("glcm.h2d"):
+                x = jnp.asarray(stack)
+                out_dev = plan(x)
+                jax.block_until_ready(x)
+            t_launch0 = self._clock()
+            with tr.span("glcm.launch"):
+                jax.block_until_ready(out_dev)
             t_launch = self._clock()
-            out = np.asarray(out_dev)
+            with tr.span("glcm.readback"):
+                out = np.asarray(out_dev)
         except Exception as exc:
             # Post-mortem before propagating: the flight ring holds the
             # dispatches leading up to the failure.
             self.flight.record(
-                "dispatch_error", workload=w.wid, name=w.name,
-                bucket=bucket, occupancy=k,
-                tickets=[r.ticket for r in reqs],
+                "dispatch_error", workload=w.wid, name=w.name, bucket=bucket,
+                occupancy=k, tickets=[r.ticket for r in reqs],
                 error=f"{type(exc).__name__}: {exc}")
             self.last_incident = self.flight.dump(
                 reason=f"dispatch error in workload {w.wid} ({w.name}): "
                        f"{type(exc).__name__}: {exc}")
             raise
         t_done = self._clock()
-        pad_ms = (t_disp - t_pad0) * 1e3
-        launch_ms = (t_launch - t_disp) * 1e3
-        readback_ms = (t_done - t_launch) * 1e3
+        phase_ms = {"pad": (t_h2d - t_pad0) * 1e3,
+                    "h2d": (t_launch0 - t_h2d) * 1e3,
+                    "launch": (t_launch - t_launch0) * 1e3,
+                    "readback": (t_done - t_launch) * 1e3}
         for i, r in enumerate(reqs):
             self._pending_wid.pop(r.ticket, None)
             self._store_result(r.ticket, w.wid, out[i])
-            w.queue_ms.append((t_disp - r.submitted_at) * 1e3)
-            w.service_ms.append((t_done - t_disp) * 1e3)
+            w.queue_ms.append((t_h2d - r.submitted_at) * 1e3)
+            w.service_ms.append((t_done - t_h2d) * 1e3)
             w.e2e_ms.append((t_done - r.submitted_at) * 1e3)
-            w.m_phase["queue"].observe((t_disp - r.submitted_at) * 1e3)
-        w.pad_ms.append(pad_ms)
-        w.launch_ms.append(launch_ms)
-        w.readback_ms.append(readback_ms)
-        w.m_phase["pad"].observe(pad_ms)
-        w.m_phase["launch"].observe(launch_ms)
-        w.m_phase["readback"].observe(readback_ms)
+            w.m_phase["queue"].observe((t_h2d - r.submitted_at) * 1e3)
+        for phase, ms in phase_ms.items():
+            getattr(w, f"{phase}_ms").append(ms)
+            w.m_phase[phase].observe(ms)
         w.batches += 1
         w.served += k
         w.m_batches.inc()
@@ -874,32 +895,14 @@ class GLCMEngine:
         w.occupancy[bucket][k] = w.occupancy[bucket].get(k, 0) + 1
         self.batches_dispatched += 1
         self.images_served += k
-        self.dispatch_log.append({
-            "workload": w.wid, "bucket": bucket, "occupancy": k,
-            "tickets": tuple(r.ticket for r in reqs),
-            "deadline": deadline,
-        })
         self.flight.record(
             "dispatch", workload=w.wid, name=w.name, bucket=bucket,
             occupancy=k, deadline=deadline, queue_depth=len(w.queue),
-            pad_ms=round(pad_ms, 3), launch_ms=round(launch_ms, 3),
-            readback_ms=round(readback_ms, 3))
-        tr = self.tracer
+            tickets=[r.ticket for r in reqs],
+            **{f"{ph}_ms": round(ms, 3) for ph, ms in phase_ms.items()})
         if tr.enabled:
-            # One batch-level span tree on the engine's track…
-            sid = tr.add_span(
-                "glcm.dispatch", t_pad0, t_done, workload=w.name,
-                bucket=bucket, occupancy=k, deadline=deadline,
-                backend=plan.spec.scheme)
-            tr.add_span("glcm.pad", t_pad0, t_disp, parent=sid,
-                        workload=w.name)
-            tr.add_span("glcm.launch", t_disp, t_launch, parent=sid,
-                        workload=w.name, backend=plan.spec.scheme,
-                        synced=True)
-            tr.add_span("glcm.readback", t_launch, t_done, parent=sid,
-                        workload=w.name)
-            # …and one span tree per request under its ticket correlation
-            # id: the request's whole life, submit() to result ready.
+            # One span tree per request under its ticket correlation id:
+            # the request's whole life, submit() to result ready.
             for r in reqs:
                 root = tr.add_span(
                     "glcm.request", r.submitted_at, t_done, corr=r.ticket,
@@ -907,9 +910,11 @@ class GLCMEngine:
                     bucket=bucket, occupancy=k, deadline=deadline)
                 tr.add_span("glcm.queue_wait", r.submitted_at, t_pad0,
                             parent=root, corr=r.ticket)
-                tr.add_span("glcm.pad", t_pad0, t_disp, parent=root,
+                tr.add_span("glcm.pad", t_pad0, t_h2d, parent=root,
                             corr=r.ticket)
-                tr.add_span("glcm.launch", t_disp, t_launch, parent=root,
+                tr.add_span("glcm.h2d", t_h2d, t_launch0, parent=root,
+                            corr=r.ticket)
+                tr.add_span("glcm.launch", t_launch0, t_launch, parent=root,
                             corr=r.ticket, backend=plan.spec.scheme,
                             synced=True)
                 tr.add_span("glcm.readback", t_launch, t_done, parent=root,

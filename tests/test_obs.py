@@ -44,6 +44,66 @@ def test_disabled_tracer_records_nothing_and_shares_noop():
     assert len(tr) == 0
 
 
+def _profiled(tmp_path, body):
+    """Run ``body`` under a jax.profiler capture; the host events whose
+    names start with ``repro.``, as {name: {stat: value}}."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return {e.name: dict(e.stats)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name.startswith("repro.")}
+
+
+def test_disabled_tracer_annotates_only_while_a_capture_runs(tmp_path):
+    """No capture: the shared no-op.  During a capture: a profiler
+    annotation named ``repro.<name>`` with the attributes as stats, and
+    still nothing in the ring."""
+    tr = Tracer(enabled=False)
+    assert tr.span("glcm.pad", bucket=1) is tr.span("glcm.h2d")
+
+    def body():
+        cm = tr.span("glcm.pad", bucket=1, workload="w")
+        assert cm is not tr.span("glcm.h2d")
+        with cm as sp:
+            sp.set(ignored=True)
+        with tr.span("glcm.stream_push", corr="stream-3"):
+            pass
+
+    events = _profiled(tmp_path, body)
+    assert events == {"repro.glcm.pad": {"bucket": 1, "workload": "w"},
+                      "repro.glcm.stream_push": {"corr": "stream-3"}}
+    assert len(tr) == 0
+    assert tr.span("glcm.pad") is tr.span("glcm.h2d")  # capture over
+
+
+def test_enabled_tracer_records_and_annotates_during_a_capture(tmp_path):
+    tr = Tracer(enabled=True, clock=StepClock())
+
+    def body():
+        with tr.span("glcm.dispatch", bucket=4):
+            with tr.span("glcm.launch", corr=7):
+                pass
+
+    events = _profiled(tmp_path, body)
+    assert events == {"repro.glcm.dispatch": {"bucket": 4},
+                      "repro.glcm.launch": {"corr": 7}}
+    spans = {s.name: s for s in tr.spans()}
+    assert spans["glcm.launch"].parent == spans["glcm.dispatch"].id
+    assert spans["glcm.launch"].corr == 7
+    assert spans["glcm.launch"].attrs == {}
+
+
 def test_nested_spans_build_parent_links_and_attrs():
     tr = Tracer(enabled=True, clock=StepClock())
     with tr.span("outer", workload="w") as outer:
@@ -204,9 +264,10 @@ def test_disabled_tracer_dispatch_overhead_under_two_percent():
     call itself jitters a few percent run-to-run, while the real no-op
     cost is ~0.03% of a dispatch), so measure the two terms directly:
     the per-dispatch instrumentation cost (the engine's exact traced-off
-    sequence — one no-op ``span()`` plus the ``enabled`` guards on the
-    retrospective recording) in a tight loop, and the dispatch cost as a
-    min-of-rounds, then bound their ratio."""
+    sequence — five no-op ``span()`` calls, each checking for a profiler
+    capture, plus the ``enabled`` guards on the retrospective recording)
+    in a tight loop, and the dispatch cost as a min-of-rounds, then bound
+    their ratio."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -226,10 +287,19 @@ def test_disabled_tracer_dispatch_overhead_under_two_percent():
     tr = Tracer(enabled=False)
 
     def instrumentation_only():
-        # exactly what one traced-off dispatch adds: a no-op span and the
-        # guards in front of every retrospective add_span/event call
-        with tr.span("glcm.dispatch", workload="w"):
-            pass
+        # exactly what one traced-off dispatch adds: the dispatch span
+        # around its four phase spans, all no-ops, and the guards in front
+        # of every retrospective add_span/event call
+        with tr.span("glcm.dispatch", workload="w", bucket=8, occupancy=8,
+                     deadline=False):
+            with tr.span("glcm.pad"):
+                pass
+            with tr.span("glcm.h2d"):
+                pass
+            with tr.span("glcm.launch"):
+                pass
+            with tr.span("glcm.readback"):
+                pass
         if tr.enabled:
             tr.add_span("glcm.request", 0.0, 1.0, corr=1)
         if tr.enabled:

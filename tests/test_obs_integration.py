@@ -55,9 +55,10 @@ def tracer():
 
 
 def test_submit_correlation_id_traceable_end_to_end(tracer):
-    """One submit() ticket = one span tree: queue wait, padding, launch
-    (device-synced), readback — every span carrying the ticket as its
-    correlation id, children linked to the request root."""
+    """One submit() ticket = one span tree: queue wait, padding, the
+    host→device copy, launch (device-synced), readback — every span
+    carrying the ticket as its correlation id, children linked to the
+    request root."""
     clock = FakeClock()
     eng = GLCMEngine(_cfg(batch_size=4), clock=clock, tracer=tracer)
     tickets = []
@@ -81,25 +82,32 @@ def test_submit_correlation_id_traceable_end_to_end(tracer):
         children = [s for s in spans
                     if s.parent == root.id and s.corr == t]
         names = {s.name for s in children}
-        assert names == {"glcm.queue_wait", "glcm.pad", "glcm.launch",
-                         "glcm.readback"}
+        assert names == {"glcm.queue_wait", "glcm.pad", "glcm.h2d",
+                         "glcm.launch", "glcm.readback"}
+        assert len(children) == 5
         phases = {s.name: s for s in children}
-        # contiguous phase boundaries: wait→pad→launch→readback
+        # contiguous phase boundaries: wait→pad→h2d→launch→readback
         assert root.t0 == phases["glcm.queue_wait"].t0
         assert phases["glcm.queue_wait"].t1 == phases["glcm.pad"].t0
-        assert phases["glcm.pad"].t1 == phases["glcm.launch"].t0
+        assert phases["glcm.pad"].t1 == phases["glcm.h2d"].t0
+        assert phases["glcm.h2d"].t1 == phases["glcm.launch"].t0
         assert phases["glcm.launch"].t1 == phases["glcm.readback"].t0
         assert phases["glcm.readback"].t1 == root.t1
         # the launch duration is device-synced (block_until_ready)
         assert phases["glcm.launch"].attrs["synced"] is True
         assert phases["glcm.launch"].attrs["backend"]
 
-    # plus one batch-level dispatch tree on the engine's own track
+    # plus one live dispatch span, its four phases nested in order inside
     (disp,) = by_name["glcm.dispatch"]
-    assert disp.attrs["occupancy"] == 4
+    assert disp.attrs == {"workload": "default", "bucket": 4, "occupancy": 4,
+                          "deadline": False}
     disp_children = [s for s in spans if s.parent == disp.id]
-    assert {s.name for s in disp_children} == {"glcm.pad", "glcm.launch",
-                                               "glcm.readback"}
+    assert [s.name for s in disp_children] == [
+        "glcm.pad", "glcm.h2d", "glcm.launch", "glcm.readback"]
+    assert disp.t0 <= disp_children[0].t0
+    for a, b in zip(disp_children, disp_children[1:]):
+        assert a.t1 <= b.t0
+    assert disp_children[-1].t1 <= disp.t1
 
     # results still served normally
     assert eng.result(tickets[0]).shape[0] == 1
@@ -147,12 +155,20 @@ def test_stats_expose_per_phase_dispatch_breakdown():
     eng.submit(IMGS[0])
     eng.submit(IMGS[1])
     w = eng.stats()["workloads"][0]
-    for phase in ("pad_ms", "launch_ms", "readback_ms"):
+    for phase in ("pad_ms", "h2d_ms", "launch_ms", "readback_ms"):
         assert w[phase]["n"] == 1, phase
         assert w[phase]["p50"] >= 0.0
+    # the four phases are disjoint parts of the service time
+    phases_ms = sum(w[ph]["mean"] for ph in ("h2d_ms", "launch_ms",
+                                              "readback_ms"))
+    assert phases_ms == pytest.approx(w["service_ms"]["mean"])
     st = eng.stats()
     assert st["flight_records"] >= 1  # dispatch record always kept
     assert st["incidents"] == 0
+    (rec,) = eng.flight.records()
+    assert rec["kind"] == "dispatch" and rec["tickets"] == [0, 1]
+    for phase in ("pad_ms", "h2d_ms", "launch_ms", "readback_ms"):
+        assert rec[phase] >= 0.0, phase
 
 
 def test_serve_metrics_populate_global_registry():
@@ -169,7 +185,10 @@ def test_serve_metrics_populate_global_registry():
     assert snap["repro_serve_batches_total"]["series"][0]["value"] == 1
     phase_series = snap["repro_serve_phase_ms"]["series"]
     phases = {s["labels"]["phase"] for s in phase_series}
-    assert phases == {"queue", "pad", "launch", "readback"}
+    assert phases == {"queue", "pad", "h2d", "launch", "readback"}
+    counts = {s["labels"]["phase"]: s["count"] for s in phase_series}
+    assert counts == {"queue": 2, "pad": 1, "h2d": 1, "launch": 1,
+                      "readback": 1}
     # scrape-ready exposition includes the histogram series
     assert "repro_serve_phase_ms_bucket" in reg.to_prometheus()
 

@@ -34,6 +34,11 @@ def _cfg(**kw):
     return GLCMServeConfig(**kw)
 
 
+def dispatches(eng):
+    """The engine's dispatch records, oldest first, from its flight ring."""
+    return [r for r in eng.flight.records() if r["kind"] == "dispatch"]
+
+
 class FakeClock:
     def __init__(self):
         self.t = 0.0
@@ -100,7 +105,7 @@ def test_deadline_dispatches_single_queued_request():
     assert eng.poll() == 0          # deadline not reached: still queued
     clock.advance(0.2)
     assert eng.poll() == 1          # expired: partial dispatch fires
-    entry = eng.dispatch_log[-1]
+    entry = dispatches(eng)[-1]
     assert entry["deadline"] and entry["bucket"] == 1 and entry["occupancy"] == 1
     assert eng.stats()["workloads"][0]["deadline_dispatches"] == 1
     ref = GLCMEngine(_cfg(batch_size=1)).map(IMGS[:1])[0]
@@ -126,14 +131,14 @@ def test_deadline_dispatch_takes_largest_full_bucket():
         eng.submit(im)
     clock.advance(1.1)
     eng.poll()
-    entry = eng.dispatch_log[-1]
+    entry = dispatches(eng)[-1]
     assert entry["bucket"] == 2 and entry["occupancy"] == 2
     occ = eng.stats()["workloads"][0]["batch_occupancy"]
     assert occ == {2: {2: 1}}
     # the leftover request is younger: its deadline fires later, alone
     clock.advance(1.1)
     eng.poll()
-    assert eng.dispatch_log[-1]["bucket"] == 1
+    assert dispatches(eng)[-1]["bucket"] == 1
     # padding only below the smallest bucket: explicit buckets (2, 8),
     # one queued request past deadline → padded bucket-2 launch
     eng2 = GLCMEngine(
@@ -141,7 +146,7 @@ def test_deadline_dispatch_takes_largest_full_bucket():
     eng2.submit(IMGS[0])
     clock.advance(1.1)
     eng2.poll()
-    entry = eng2.dispatch_log[-1]
+    entry = dispatches(eng2)[-1]
     assert entry["bucket"] == 2 and entry["occupancy"] == 1
 
 
@@ -152,7 +157,7 @@ def test_deadline_fires_inside_submit_too():
     clock.advance(2.0)
     eng.submit(IMGS[1])             # submit advances the loop: both dispatch
     assert eng.batches_dispatched == 1
-    assert eng.dispatch_log[-1]["occupancy"] == 2
+    assert dispatches(eng)[-1]["occupancy"] == 2
 
 
 def test_next_deadline_reports_earliest_expiry():
@@ -180,7 +185,7 @@ def test_per_workload_deadline_override():
     eng.submit(IMGS[1], workload=wid)
     clock.advance(5.0)
     assert eng.poll() == 1          # only the deadline workload fires
-    assert eng.dispatch_log[-1]["workload"] == wid
+    assert dispatches(eng)[-1]["workload"] == wid
     assert len(eng._workloads[0].queue) == 1
 
 
@@ -286,7 +291,7 @@ def test_priorities_drain_high_before_low_under_load():
     assert eng.batches_dispatched == 0
     eng.resume()                     # backlog drains in priority order
     assert eng.batches_dispatched == 4
-    order = [t for d in eng.dispatch_log for t in d["tickets"]]
+    order = [t for d in dispatches(eng) for t in d["tickets"]]
     assert order[:4] == high and order[4:] == low
     # results are still correct per ticket despite reordering
     ref = GLCMEngine(_cfg(batch_size=2)).map(IMGS[:8])
@@ -307,7 +312,7 @@ def test_priority_ageing_prevents_starvation():
         eng.submit(im, priority=1)
     clock.advance(2.0)               # old request is past its deadline
     eng.resume()
-    assert old in eng.dispatch_log[0]["tickets"]
+    assert old in dispatches(eng)[0]["tickets"]
 
 
 # ---------------------------------------------------------------------------
