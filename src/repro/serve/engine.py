@@ -35,12 +35,13 @@ job is to keep launches *full and frequent* under real traffic:
   p50/p95/p99 queue/service/end-to-end latency, a per-phase breakdown
   (pad / h2d / launch / readback — the host→device copy and the launch
   each end in ``block_until_ready``: ``h2d_ms`` runs until the batch is
-  on the device, ``launch_ms`` from there until the output is ready), a
-  batch-occupancy histogram, shed and result-eviction counters — plus
-  the engine-wide plan-cache hit rate.  Counters/gauges/latency
-  histograms also stream into the process-global
-  :mod:`repro.obs.metrics` registry (Prometheus text via
-  ``get_registry().to_prometheus()``).
+  on the device, ``launch_ms`` from there until the output is ready; a
+  one-image bucket is handed to the copy as a view of the request's own
+  array, so its pad builds no batch), a batch-occupancy histogram, shed,
+  result-eviction and unstacked-batch counters — plus the engine-wide
+  plan-cache hit rate.  Counters/gauges/latency histograms also stream
+  into the process-global :mod:`repro.obs.metrics` registry (Prometheus
+  text via ``get_registry().to_prometheus()``).
 * **Tracing.**  Each dispatch runs inside live spans: ``glcm.dispatch``
   around ``glcm.pad`` / ``glcm.h2d`` / ``glcm.launch`` /
   ``glcm.readback``.  While a ``jax.profiler`` capture runs they appear
@@ -279,6 +280,7 @@ class _Workload:
         self.shed = 0
         self.results_evicted = 0
         self.batches = 0
+        self.unstacked_batches = 0
         self.deadline_dispatches = 0
         self.occupancy: dict[int, dict[int, int]] = {}  # bucket → {occ: n}
         self.queue_ms: collections.deque = collections.deque(maxlen=stats_window)
@@ -302,6 +304,10 @@ class _Workload:
             workload=name)
         self.m_batches = reg.counter(
             "repro_serve_batches_total", "batches dispatched", workload=name)
+        self.m_unstacked = reg.counter(
+            "repro_serve_unstacked_batches_total",
+            "batches handed to the copy as the request's own buffer",
+            workload=name)
         self.m_deadline = reg.counter(
             "repro_serve_deadline_dispatches_total",
             "partial batches launched by deadline expiry", workload=name)
@@ -755,6 +761,7 @@ class GLCMEngine:
                 "shed": w.shed,
                 "results_evicted": w.results_evicted,
                 "batches": w.batches,
+                "unstacked_batches": w.unstacked_batches,
                 "deadline_dispatches": w.deadline_dispatches,
                 "batch_occupancy": {
                     b: dict(h) for b, h in sorted(w.occupancy.items())
@@ -840,12 +847,17 @@ class GLCMEngine:
         # dispatch-return time.  The kernel is enqueued behind the copy
         # before the wait on the copy: launched after it instead, the host's
         # release of the copied batch (~24 ms at 16384², PERF.md) lands on
-        # this thread while the device idles.
+        # this thread while the device idles.  One request in a bucket of
+        # one is handed to the copy as a view of its own array: no batch.
+        unstacked = k == bucket == 1
         try:
             plan = self._plan_for(w, bucket)
             t_pad0 = self._clock()
             with tr.span("glcm.pad"):
-                stack, _ = pad_stack([r.image for r in reqs], bucket)
+                if unstacked:
+                    stack = reqs[0].image[None]
+                else:
+                    stack, _ = pad_stack([r.image for r in reqs], bucket)
             t_h2d = self._clock()
             with tr.span("glcm.h2d"):
                 x = jnp.asarray(stack)
@@ -887,6 +899,9 @@ class GLCMEngine:
         w.served += k
         w.m_batches.inc()
         w.m_served.inc(k)
+        if unstacked:
+            w.unstacked_batches += 1
+            w.m_unstacked.inc()
         if deadline:
             w.deadline_dispatches += 1
             w.m_deadline.inc()

@@ -183,6 +183,8 @@ def test_serve_metrics_populate_global_registry():
     assert by_labels[(("workload", "default"),)] == 2
     assert snap["repro_serve_served_total"]["series"][0]["value"] == 2
     assert snap["repro_serve_batches_total"]["series"][0]["value"] == 1
+    # a full batch of two is stacked, not handed over as one request's buffer
+    assert snap["repro_serve_unstacked_batches_total"]["series"][0]["value"] == 0
     phase_series = snap["repro_serve_phase_ms"]["series"]
     phases = {s["labels"]["phase"] for s in phase_series}
     assert phases == {"queue", "pad", "h2d", "launch", "readback"}

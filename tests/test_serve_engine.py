@@ -5,12 +5,16 @@ coexistence.
 Deadline tests inject a fake clock (``GLCMEngine(cfg, clock=...)``) so
 deadline expiry is deterministic virtual time, never a sleep."""
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.plan import bucket_sizes, pick_bucket, plan_cache_clear
+from repro.core import pipeline
+from repro.core.plan import (bucket_sizes, compile_plan, pick_bucket,
+                             plan_cache_clear)
 from repro.core.pipeline import pad_stack
 from repro.core.spec import GLCMSpec
+from repro.obs.metrics import get_registry
 from repro.serve.engine import GLCMEngine, GLCMServeConfig, QueueFullError
 
 RNG = np.random.default_rng(7)
@@ -135,10 +139,12 @@ def test_deadline_dispatch_takes_largest_full_bucket():
     assert entry["bucket"] == 2 and entry["occupancy"] == 2
     occ = eng.stats()["workloads"][0]["batch_occupancy"]
     assert occ == {2: {2: 1}}
+    assert eng.stats()["workloads"][0]["unstacked_batches"] == 0
     # the leftover request is younger: its deadline fires later, alone
     clock.advance(1.1)
     eng.poll()
     assert dispatches(eng)[-1]["bucket"] == 1
+    assert eng.stats()["workloads"][0]["unstacked_batches"] == 1
     # padding only below the smallest bucket: explicit buckets (2, 8),
     # one queued request past deadline → padded bucket-2 launch
     eng2 = GLCMEngine(
@@ -148,6 +154,7 @@ def test_deadline_dispatch_takes_largest_full_bucket():
     eng2.poll()
     entry = dispatches(eng2)[-1]
     assert entry["bucket"] == 2 and entry["occupancy"] == 1
+    assert eng2.stats()["workloads"][0]["unstacked_batches"] == 0
 
 
 def test_deadline_fires_inside_submit_too():
@@ -187,6 +194,89 @@ def test_per_workload_deadline_override():
     assert eng.poll() == 1          # only the deadline workload fires
     assert dispatches(eng)[-1]["workload"] == wid
     assert len(eng._workloads[0].queue) == 1
+
+
+# ---------------------------------------------------------------------------
+# one-image buckets: the request's own buffer, no host batch
+# ---------------------------------------------------------------------------
+
+U8 = np.random.default_rng(11).integers(0, 256, (2 * SHAPE[0], 2 * SHAPE[1]),
+                                        dtype=np.uint8)
+
+
+def _u8_image(layout):
+    if layout == "contiguous":
+        return U8[:SHAPE[0], :SHAPE[1]].copy()
+    if layout == "strided":
+        return U8[::2, ::2]             # a view: not C-contiguous
+    img = U8[SHAPE[0]:, SHAPE[1]:].copy()
+    img.setflags(write=False)
+    return img
+
+
+def _spy_pad_stack(monkeypatch):
+    """Record every stack the engine builds through pad_stack."""
+    built = []
+
+    def spy(images, size):
+        stack, k = pad_stack(images, size)
+        built.append(stack)
+        return stack, k
+
+    monkeypatch.setattr(pipeline, "pad_stack", spy)
+    return built
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "strided", "readonly"])
+def test_one_image_bucket_is_served_from_the_request_buffer(layout,
+                                                            monkeypatch):
+    """A lone request in a bucket of one goes to the copy as a view of its
+    own array: no pad_stack, the caller's array untouched, and the answer
+    bit-identical to the plan run on the stacked image."""
+    img = _u8_image(layout)
+    assert img.flags.c_contiguous == (layout != "strided")
+    before = img.copy()
+    built = _spy_pad_stack(monkeypatch)
+    eng = GLCMEngine(_cfg(batch_size=1))
+    counter = get_registry().counter(
+        "repro_serve_unstacked_batches_total", workload="default")
+    count0 = counter.value
+    got = [eng.result(eng.submit(img)) for _ in range(3)]
+    plan = compile_plan(eng.spec, (1, *SHAPE), features=eng.cfg.features)
+    want = np.asarray(plan(jnp.asarray(np.stack([img]))))[0]
+    for out in got:
+        np.testing.assert_array_equal(out, want)
+    assert built == []
+    np.testing.assert_array_equal(img, before)
+    assert img.flags.writeable == (layout != "readonly")
+    st = eng.stats()["workloads"][0]
+    assert st["unstacked_batches"] == st["batches"] == 3
+    assert counter.value - count0 == 3
+
+
+@pytest.mark.parametrize("n, batch_size", [(3, 4), (2, 2)])
+def test_multi_slot_buckets_keep_the_padded_stack(n, batch_size,
+                                                  monkeypatch):
+    """A partial bucket (3 in 4) and a full bucket of 2 still go through
+    pad_stack: padded slots repeat the last image, the answers are the
+    plan's on that stack, and no batch counts as unstacked."""
+    imgs = [_u8_image("contiguous") + np.uint8(i) for i in range(n)]
+    built = _spy_pad_stack(monkeypatch)
+    eng = GLCMEngine(_cfg(batch_size=batch_size))
+    tickets = [eng.submit(im) for im in imgs]
+    eng.flush()
+    got = np.stack([eng.result(t) for t in tickets])
+    (stack,) = built
+    assert stack.shape == (batch_size, *SHAPE)
+    for slot in range(batch_size):
+        np.testing.assert_array_equal(stack[slot], imgs[min(slot, n - 1)])
+    plan = compile_plan(eng.spec, (batch_size, *SHAPE),
+                        features=eng.cfg.features)
+    want = np.asarray(plan(jnp.asarray(pad_stack(imgs, batch_size)[0])))
+    np.testing.assert_array_equal(got, want[:n])
+    st = eng.stats()["workloads"][0]
+    assert st["batches"] == 1 and st["unstacked_batches"] == 0
+    assert st["batch_occupancy"] == {batch_size: {n: 1}}
 
 
 # ---------------------------------------------------------------------------
