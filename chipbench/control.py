@@ -74,7 +74,7 @@ def readings(name: str, seeds, seconds: float) -> list[dict]:
             RealClock, lambda _: contextlib.nullcontext())
         want, faults = fault_errors(pool, [r.pool_index for r in window.records],
                                     prep.config)
-        program = run.reference_errors(window.records, want)
+        program = run.reference_errors(prep.kind, window.records, want)
         row = {"seed": seed, "requests": len(window.records), "program_err": max(program),
                **{f"{k}_err": max(v) for k, v in faults.items()},
                **{f"{k}_entries": v for k, v in faults.items()}}

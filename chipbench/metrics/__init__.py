@@ -4,9 +4,11 @@ A reader that finds nothing to read returns None and the metric is left
 out of the result line. ``ctx`` is ``chipbench.run.Context``.
 
 The helpers below are shared by the kernel readers: a kernel is found in
-the trace by its HLO instruction name, which the Pallas call takes from
-the jitted wrapper (``glcm_fused_pallas.1``); ``ANY_KERNEL`` matches every
-Pallas GLCM kernel of the program.
+the trace by a regular expression on its HLO instruction name, which the
+Pallas call takes from the jitted wrapper (``glcm_fused_pallas.1``), so
+the reader of a new kernel is one new file that passes its own pattern.
+``KERNELS`` holds the patterns of the kernels read today; ``ANY_KERNEL``
+matches every Pallas GLCM kernel of the program.
 """
 
 from __future__ import annotations
@@ -30,20 +32,22 @@ def reader(name: str):
     return mod.read
 
 
-def kernel_ms(ctx, kernel: str) -> float | None:
-    """Device ms of one kernel per request served in the traced window."""
+def kernel_ms(ctx, pattern: str) -> float | None:
+    """Device ms per request served in the traced window of the kernels
+    whose HLO name matches ``pattern``."""
     if ctx.trace is None or not ctx.records:
         return None
-    ns = ctx.trace.matching_ns(KERNELS[kernel])
+    ns = ctx.trace.matching_ns(pattern)
     return ns / 1e6 / len(ctx.records) if ns else None
 
 
-def roofline_pct(ctx, kernel: str) -> float | None:
-    """Least time of the served requests' work over the kernel's device
-    time, in %; notes which term bounds it."""
+def roofline_pct(ctx, kernel: str, pattern: str) -> float | None:
+    """Least time of the served requests' work over the device time of the
+    kernels whose HLO name matches ``pattern``, in %; notes which term
+    bounds it under ``<kernel>_roofline_bound``."""
     if ctx.trace is None or not ctx.records:
         return None
-    ns = ctx.trace.matching_ns(KERNELS[kernel])
+    ns = ctx.trace.matching_ns(pattern)
     if not ns:
         return None
     n = len(ctx.records)

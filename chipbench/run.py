@@ -7,19 +7,20 @@ The cell is an entry of ``workloads`` in ``BENCHMARK.json``. Its traffic is
 ``chipbench/workloads/<cell>.json`` (loop kind, clients, shape, batch and
 buckets, deadline, pool), its configuration
 ``chipbench/configs/<config>.json`` (the GLCM spec as served, the expected
-backend). The run:
+backend, the limit of the comparison), and the configuration's ``kind``
+names the module ``chipbench/kinds/<kind>.py`` that makes its pool, builds
+its engine, counts its work and holds its reference. The run:
 
-1. makes the cell's request pool on the device from ``--seed``
-   (``chipbench.data``), builds a ``GLCMEngine`` for the configuration,
-   checks that every bucket resolves to the expected Pallas backend, and
-   warms up the cell's own bucket shapes (set-up: process start to the
-   window);
+1. makes the cell's request pool on the device from ``--seed``, builds a
+   ``GLCMEngine`` for the configuration, checks that every bucket's served
+   plan resolves to the expected Pallas backend, and warms up the cell's
+   own bucket shapes (set-up: process start to the window);
 2. drives ``submit`` / ``result`` from the client's side with the cell's
    loop (``chipbench/traffic/<loop>.py``) for ``--seconds``;
 3. with ``--trace 1``, traces that window with the profiler and reads each
    per-layer metric with its reader (``chipbench/metrics/<metric>.py``);
-4. frees the engine and compares every answer of the window with the plain
-   reference (``chipbench.reference``), computed once per pool entry.
+4. frees the engine and compares every answer of the window with the
+   kind's plain reference, computed once per pool entry.
 
 The last line of stdout is one JSON object; the numbers compared for
 ``correct`` come last there and on stderr. Without a TPU, or with fewer
@@ -49,7 +50,7 @@ for _p in (ROOT, ROOT / "src"):
 
 import numpy as np  # noqa: E402
 
-from chipbench import data, reference, roofline, trace  # noqa: E402
+from chipbench import data, kinds, trace  # noqa: E402
 from chipbench.metrics import reader  # noqa: E402
 from chipbench.traffic import RealClock, loop  # noqa: E402
 
@@ -101,30 +102,21 @@ def require_accelerator(chips: int):
 
 
 def build_engine(cell: dict, config: dict):
-    from repro.core.spec import GLCMSpec
-    from repro.serve.engine import GLCMEngine, GLCMServeConfig
-
-    s = config["spec"]
-    spec = GLCMSpec(
-        levels=s["levels"], pairs=tuple(tuple(p) for p in s["pairs"]), ndim=s["ndim"],
-        quantize=s["quantize"], symmetric=s["symmetric"], normalize=s["normalize"],
-    )
-    return GLCMEngine(GLCMServeConfig(
-        spec=spec, image_shape=tuple(cell["shape"]), batch_size=cell["batch"],
-        buckets=tuple(cell["buckets"]), features=True, max_wait_ms=cell["max_wait_ms"],
-        stats_window=1 << 20,
-    ))
+    return kinds.of(config).build_engine(cell, config)
 
 
 def check_backend(engine, cell: dict, config: dict) -> str:
-    """Every bucket's plan resolves to the configuration's backend, on the
-    device and compiled (not the host path, not interpret mode)."""
+    """The plan the engine serves each bucket with resolves to the
+    configuration's backend, on the device and compiled (not the host path,
+    not interpret mode). The engine takes its plans from the plan cache
+    under its own spec, shape and feature set, so these are its plans."""
     from repro.core.plan import compile_plan
     from repro.kernels.ops import should_interpret
 
     expect = config["expect_backend"]
     for b in cell["buckets"]:
-        plan = compile_plan(engine.spec, (b, *cell["shape"]), features=True)
+        plan = compile_plan(engine.spec, (b, *engine.cfg.image_shape),
+                            features=engine.cfg.features)
         if plan.spec.scheme != expect:
             raise HarnessError(f"bucket {b} resolved to {plan.spec.scheme!r}, "
                                f"expected {expect!r}")
@@ -161,7 +153,29 @@ class Context:
     trace: trace.Reduced | None
     device_kind: str
     work: tuple                 # (ops, bytes) of one request
+    span_ns: dict = dataclasses.field(default_factory=dict)  # program span → ns in the window
+    stats: dict = dataclasses.field(default_factory=dict)    # window_stats of the engine
     notes: dict = dataclasses.field(default_factory=dict)
+
+
+GAUGES = ("batch_size", "ndim", "queue_depth")
+
+
+def window_stats(before: dict, after: dict) -> dict:
+    """The engine's ``stats()`` entry of one workload over the window: each
+    count as its change (``GAUGES`` as they stand at the end), each
+    per-batch sample set (``pad_ms``, ``h2d_ms``, ...) as its number and
+    total of the window's samples, everything else as it stands."""
+    out = {}
+    for key, v in after.items():
+        b = before.get(key)
+        if isinstance(v, int) and not isinstance(v, bool) and key not in GAUGES:
+            out[key] = v - b
+        elif isinstance(v, dict) and {"n", "mean"} <= set(v):
+            out[key] = {"n": v["n"] - b["n"], "total": v["mean"] * v["n"] - b["mean"] * b["n"]}
+        else:
+            out[key] = v
+    return out
 
 
 def end_to_end(window, cell: dict, setup_s: float) -> dict:
@@ -178,16 +192,15 @@ def reports(metric: dict, workload: str) -> bool:
     return "workloads" not in metric or workload in metric["workloads"]
 
 
-def reference_answers(pool, indices, config) -> dict:
-    """The float64 reference answer of each named pool entry."""
-    return {i: reference.answer(reference.raw_counts(pool[i], config), config)
-            for i in sorted(set(indices))}
+def reference_answers(kind, pool, indices, config) -> dict:
+    """The kind's reference answer of each named pool entry."""
+    return {i: kind.reference(pool[i], config) for i in sorted(set(indices))}
 
 
-def reference_errors(records, want) -> list[float]:
-    """reference.feature_error of each record's answer against the
-    reference answer of its pool entry."""
-    return [reference.feature_error(r.answer, want[r.pool_index]) for r in records]
+def reference_errors(kind, records, want) -> list[float]:
+    """The kind's error of each record's answer against the reference
+    answer of its pool entry."""
+    return [kind.error(r.answer, want[r.pool_index]) for r in records]
 
 
 @dataclasses.dataclass
@@ -195,6 +208,7 @@ class Prepared:
     bench: dict
     cell: dict
     config: dict
+    kind: object
     device: object
     pool: list
     engine: object
@@ -220,17 +234,18 @@ def prepare(name: str, seed: int) -> Prepared:
     import jax
 
     bench, entry, cell, config = load_cell(name)
+    kind = kinds.of(config)
     enable_cache()
     dev = require_accelerator(entry["chips"])
     log(f"device platform={dev.platform} kind={dev.device_kind} "
         f"count={len(jax.devices())} at {time.monotonic() - T_START:.3f} s")
-    pool = data.make_pool(cell["pool"], cell["shape"], seed)
+    pool = kind.make_pool(cell["pool"], cell["shape"], seed)
     log(f"pool of {len(pool)} made by {time.monotonic() - T_START:.3f} s")
     engine = build_engine(cell, config)
     log(f"backend={check_backend(engine, cell, config)} buckets={cell['buckets']}")
     warm_up(engine, pool, cell["buckets"])
     log(f"engine warm by {time.monotonic() - T_START:.3f} s")
-    return Prepared(bench, cell, config, dev, pool, engine)
+    return Prepared(bench, cell, config, kind, dev, pool, engine)
 
 
 def run_cell(name: str, seed: int, seconds: float, traced: bool,
@@ -238,7 +253,8 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
     import jax
 
     prep = prepare(name, seed)
-    bench, cell, config, dev, pool = prep.bench, prep.cell, prep.config, prep.device, prep.pool
+    bench, cell, config, kind = prep.bench, prep.cell, prep.config, prep.kind
+    dev, pool = prep.device, prep.pool
     engine = prep.engine
     del prep
 
@@ -285,10 +301,9 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
     log(f"requests={len(window.records)} window_s={window.seconds}")
 
     stats = engine.stats()["workloads"][0]
-    batches = stats["batches"] - before["batches"]
-    phase_ms = {ph: stats[f"{ph}_ms"]["mean"] * stats[f"{ph}_ms"]["n"]
-                - before[f"{ph}_ms"]["mean"] * before[f"{ph}_ms"]["n"]
-                for ph in ("pad", "launch", "readback")}
+    in_window = window_stats(before, stats)
+    batches = in_window["batches"]
+    phase_ms = {ph: in_window[f"{ph}_ms"]["total"] for ph in ("pad", "launch", "readback")}
     log(f"engine ms per batch in window ({batches} batches): "
         + " ".join(f"{ph}={ms / max(batches, 1):.3f}" for ph, ms in phase_ms.items()))
 
@@ -299,13 +314,11 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
             if reports(m, name):
                 metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
     else:
-        spec = config["spec"]
         ctx = Context(
             cell=cell, config=config, records=window.records,
-            served=stats["served"] - before["served"], phase_ms=phase_ms, trace=reduced,
-            device_kind=dev.device_kind,
-            work=roofline.work(cell["shape"], reference.offsets(spec["pairs"], spec["ndim"]),
-                               spec["levels"], pool[0].dtype.itemsize),
+            served=in_window["served"], phase_ms=phase_ms, trace=reduced,
+            device_kind=dev.device_kind, work=kind.work(cell, config, pool),
+            span_ns=reduced.span_ns, stats=in_window,
         )
         log(f"engine stats: {json.dumps(stats, default=str)}")
         for m in bench["per_layer"]:
@@ -325,7 +338,7 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
         device["window_s"] = reduced.window_ns / 1e9
         result["breakdown"] = {
             "device_ops": [[k, ns / 1e9] for k, ns in reduced.top_ops()],
-            "idle_gaps": [[k, ns / 1e9] for k, ns in reduced.gap_totals()],
+            "idle_gaps": [[k, ns / 1e9] for k, ns in reduced.idle_by_label()],
         }
         log(f"longest idle gaps (ms): "
             f"{[(k, ns / 1e6) for k, ns in sorted(reduced.gaps, key=lambda g: -g[1])[:10]]}")
@@ -334,17 +347,16 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
 
     gc.unfreeze()
     t0 = time.monotonic()
-    want = reference_answers(pool, [r.pool_index for r in window.records], config)
-    errors = reference_errors(window.records, want)
+    want = reference_answers(kind, pool, [r.pool_index for r in window.records], config)
+    errors = reference_errors(kind, window.records, want)
     log(f"reference compared {len(errors)} answers in {time.monotonic() - t0:.3f} s")
     limit = float(config["feature_err_limit"])
     failed = sum(not e <= limit for e in errors)
     worst = max(errors) if errors else float("inf")
     if errors:
         rec = window.records[int(np.argmax(errors))]
-        k, f = reference.worst_entry(rec.answer, want[rec.pool_index])
-        log(f"largest feature_err: pool entry {rec.pool_index}, offset {k}, "
-            f"{reference.FEATURE_NAMES[f]}")
+        log(f"largest feature_err: pool entry {rec.pool_index}, "
+            f"{kind.worst(rec.answer, want[rec.pool_index])}")
     checks = {"feature_err": {"value": worst, "limit": limit}}
     log(f"check feature_err={worst!r} limit={limit!r} failed={failed}")
     return {"correct": bool(errors) and failed == 0, "attempted": len(window.records),

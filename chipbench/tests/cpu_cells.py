@@ -1,15 +1,17 @@
 """The benchmark's cells shrunk to sizes a CPU test run holds, for driving
 ``chipbench.run`` without a chip: the same files, loop, engine and
-reference, with small shapes and the CPU's backend expected in place of
-the Pallas kernel."""
+reference, at the size and with the backend that each configuration's
+kind gives for the CPU (``cpu_cell``), in place of the Pallas kernel."""
+
+import json
+from pathlib import Path
 
 import jax
 
-from chipbench import run
+from chipbench import kinds, run
 
-SMALL = {
-    "paper_table3.16k_closed": {"shape": [48, 40]},
-}
+BENCH = json.loads((Path(run.__file__).resolve().parents[1] / "BENCHMARK.json").read_text())
+CELLS = sorted(w["name"] for w in BENCH["workloads"])
 
 
 def shrink(monkeypatch):
@@ -18,8 +20,8 @@ def shrink(monkeypatch):
 
     def load_cell(name):
         bench, entry, cell, config = real(name)
-        cell = dict(cell, **SMALL[name])
-        return bench, entry, cell, dict(config, expect_backend="onehot")
+        cell, backend = kinds.of(config).cpu_cell(cell, config)
+        return bench, entry, cell, dict(config, expect_backend=backend)
 
     monkeypatch.setattr(run, "load_cell", load_cell)
     monkeypatch.setattr(run, "require_accelerator", lambda chips: jax.devices()[0])

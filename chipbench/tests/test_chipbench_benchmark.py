@@ -42,6 +42,7 @@ def test_cell_files_exist_and_agree(cell):
     assert (ROOT / f"chipbench/traffic/{traffic['loop']}.py").is_file()
     config = json.loads((ROOT / f"chipbench/configs/{w['config']}.json").read_text())
     assert config["name"] == w["config"]
+    assert (ROOT / f"chipbench/kinds/{config['kind']}.py").is_file()
     assert traffic["buckets"][-1] == traffic["batch"]
     assert len(traffic["shape"]) == config["spec"]["ndim"]
     assert reports(E2E["setup_s"], cell)

@@ -13,7 +13,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src"), str(Path(__file__).parent)]
 
 from chipbench import control, data, reference, run  # noqa: E402
-from cpu_cells import SMALL, shrink  # noqa: E402
+from cpu_cells import CELLS, shrink  # noqa: E402
 
 
 def stale(out, memo, x, config):
@@ -55,8 +55,11 @@ def broken_engine(monkeypatch, fault):
     monkeypatch.setattr(run, "build_engine", build)
 
 
-# Batch-1 cells: no batch to leave half of, no exchange between chips.
-FAULTS = [(name, fault) for name in sorted(SMALL) for fault in (stale, altered, uncounted)]
+# The cells of whole-image configurations, whose reference and planted
+# faults these are. Batch-1 cells: no batch to leave half of, no exchange
+# between chips.
+IMAGE_CELLS = [c for c in CELLS if run.load_cell(c)[3]["kind"] == "image"]
+FAULTS = [(name, fault) for name in IMAGE_CELLS for fault in (stale, altered, uncounted)]
 
 
 @pytest.mark.parametrize("name,fault", FAULTS, ids=lambda x: getattr(x, "__name__", x))
@@ -78,13 +81,13 @@ def planted_fault_errors(monkeypatch, name, fault):
         yield max(errors[fault]), config["feature_err_limit"]
 
 
-@pytest.mark.parametrize("name", sorted(SMALL))
+@pytest.mark.parametrize("name", IMAGE_CELLS)
 def test_bfloat16_control_fails_the_limit(monkeypatch, name):
     for err, limit in planted_fault_errors(monkeypatch, name, "control"):
         assert err > limit
 
 
-@pytest.mark.parametrize("name", sorted(SMALL))
+@pytest.mark.parametrize("name", IMAGE_CELLS)
 def test_uncounted_block_fails_the_limit(monkeypatch, name):
     for err, limit in planted_fault_errors(monkeypatch, name, "uncounted"):
         assert err > limit

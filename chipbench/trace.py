@@ -1,22 +1,32 @@
-"""Profiler trace → device busy time, per-op device time and labelled idle
-gaps.
+"""Profiler trace → device busy time, per-op device time, the program's
+span time and labelled idle gaps.
 
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` writes into a small
-plain form (which is also what the test fixture holds):
+plain form (which is also what the test fixtures hold):
 
   {"devices": [{"name": "/device:TPU:0", "ops": [[op, start_ns, dur_ns], ...]}],
-   "host": [[span, start_ns, dur_ns], ...]}
+   "host": [[span, start_ns, dur_ns], ...],
+   "program": [[span, start_ns, dur_ns], ...]}
 
 ``ops`` are the events of each device plane's "XLA Ops" line, named by
 their HLO instruction (``glcm_fused_pallas.1``, ``fusion.3``, ...); ``host``
 holds the harness's own ``jax.profiler.TraceAnnotation`` spans (names
-starting with ``chipbench.``, prefix dropped). Host and device events share
-the profiler's clock.
+starting with ``chipbench.``, prefix dropped); ``program`` the program's
+(``repro.``, prefix dropped: the engine's ``glcm.dispatch`` around
+``glcm.pad``, ``glcm.h2d``, ``glcm.launch`` and ``glcm.readback``). Host
+and device events share the profiler's clock. A trace without
+``program`` reads as one in which the program wrote no span.
 
 ``reduce`` takes the window from the harness's ``window`` span, clips every
 op to it, and per device forms the union of op intervals: busy time.
 Every stretch of the window outside that union is an idle gap, labelled by
-the host span that overlaps it most ("other" where none does).
+the host span that overlaps it most ("other" where none does). Over the
+same window it adds:
+
+* ``span_ns``: program span name → ns inside the window;
+* ``idle_by_label``: every idle gap cut at program-span edges, each piece
+  labelled by the innermost program span open over it, else by the gap's
+  own harness label. With no program spans it equals ``gap_totals``.
 """
 
 from __future__ import annotations
@@ -28,6 +38,7 @@ import os
 import re
 
 PREFIX = "chipbench."
+PROGRAM_PREFIX = "repro."
 WINDOW = "window"
 
 
@@ -46,7 +57,7 @@ def load(xplane_path: str) -> dict:
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(xplane_path)
-    devices, host = [], []
+    devices, host, program = [], [], []
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             ops = [[_short(e.name), e.start_ns, e.duration_ns]
@@ -54,10 +65,12 @@ def load(xplane_path: str) -> dict:
                    for e in line.events]
             devices.append({"name": plane.name, "ops": ops})
         elif plane.name == "/host:CPU":
-            host.extend([e.name[len(PREFIX):], e.start_ns, e.duration_ns]
-                        for line in plane.lines for e in line.events
-                        if e.name.startswith(PREFIX))
-    return {"devices": devices, "host": host}
+            for line in plane.lines:
+                for e in line.events:
+                    for prefix, into in ((PREFIX, host), (PROGRAM_PREFIX, program)):
+                        if e.name.startswith(prefix):
+                            into.append([e.name[len(prefix):], e.start_ns, e.duration_ns])
+    return {"devices": devices, "host": host, "program": program}
 
 
 @dataclasses.dataclass
@@ -67,6 +80,8 @@ class Reduced:
     op_ns: dict                    # op name → device ns in the window (all devices)
     gaps: list                     # [(label, ns)] every idle gap, all devices
     n_devices: int
+    span_ns: dict = dataclasses.field(default_factory=dict)   # program span → ns
+    idle_ns: dict = dataclasses.field(default_factory=dict)   # idle_by_label's totals
 
     def matching_ns(self, pattern: str) -> float:
         rx = re.compile(pattern)
@@ -84,6 +99,11 @@ class Reduced:
         for label, ns in self.gaps:
             totals[label] = totals.get(label, 0.0) + ns
         return sorted(totals.items(), key=lambda kv: -kv[1])[:n]
+
+    def idle_by_label(self, n: int = 10) -> list:
+        """Idle ns by label, largest first: program spans where they are
+        open over an idle stretch, the gap's harness label elsewhere."""
+        return sorted(self.idle_ns.items(), key=lambda kv: -kv[1])[:n]
 
 
 def _union(intervals):
@@ -110,16 +130,78 @@ def _label(s: float, e: float, spans, starts) -> str:
     return best
 
 
-def reduce(trace: dict) -> Reduced:
+def _window(trace: dict) -> tuple:
     windows = [(s, s + d) for name, s, d in trace["host"] if name == WINDOW]
     if len(windows) != 1:
         raise ValueError(f"expected one {PREFIX}{WINDOW} span, found {len(windows)}")
-    t0, t1 = windows[0]
+    return windows[0]
+
+
+def span_ns(trace: dict) -> dict:
+    """Program span name → ns inside the window ({} without program spans)."""
+    t0, t1 = _window(trace)
+    out: dict[str, float] = {}
+    for name, s, d in trace.get("program", ()):
+        a, b = max(s, t0), min(s + d, t1)
+        if b > a:
+            out[name] = out.get(name, 0.0) + (b - a)
+    return out
+
+
+def _innermost(program) -> list:
+    """Disjoint, ordered pieces ``(start, end, name)`` of the program's
+    timeline, each named by the innermost span open over it; time outside
+    every span has no piece. The spans come from one thread, so they nest
+    and a stack holds the open ones."""
+    pieces, stack, cursor = [], [], None
+
+    def close_until(t):
+        nonlocal cursor
+        while stack and stack[-1][0] <= t:
+            end, name = stack.pop()
+            if end > cursor:
+                pieces.append((cursor, end, name))
+            cursor = end
+
+    for s, e, name in sorted(((s, s + d, n) for n, s, d in program),
+                             key=lambda x: (x[0], -x[1])):
+        close_until(s)
+        if stack:
+            if e > stack[-1][0]:
+                raise ValueError(f"program span {name} at {s} is not nested "
+                                 f"in {stack[-1][1]}")
+            if s > cursor:
+                pieces.append((cursor, s, stack[-1][1]))
+        cursor = s
+        stack.append((e, name))
+    close_until(float("inf"))
+    return pieces
+
+
+def _cut(s: float, e: float, label: str, pieces, starts, totals: dict) -> None:
+    """Add the idle gap [s, e) to ``totals``: each overlap with a program
+    piece to that piece's span, the rest to the gap's harness label."""
+    covered = 0.0
+    for ps, pe, name in pieces[max(bisect.bisect_right(starts, s) - 1, 0):]:
+        if ps >= e:
+            break
+        ov = min(e, pe) - max(s, ps)
+        if ov > 0:
+            totals[name] = totals.get(name, 0.0) + ov
+            covered += ov
+    if e - s - covered > 0:
+        totals[label] = totals.get(label, 0.0) + (e - s - covered)
+
+
+def reduce(trace: dict) -> Reduced:
+    t0, t1 = _window(trace)
     spans = sorted(((name, s, s + d) for name, s, d in trace["host"] if name != WINDOW),
                    key=lambda x: x[1])
     starts = [hs for _, hs, _ in spans]
+    pieces = _innermost(trace.get("program", ()))
+    piece_starts = [p[0] for p in pieces]
     op_ns: dict[str, float] = {}
-    busy, gaps = [], []
+    busy, gaps, idle_ns = [], [], {}
     for dev in trace["devices"]:
         ivs = []
         for name, s, d in dev["ops"]:
@@ -132,8 +214,11 @@ def reduce(trace: dict) -> Reduced:
         edges = [t0] + [x for iv in merged for x in iv] + [t1]
         for s, e in zip(edges[::2], edges[1::2]):
             if e > s:
-                gaps.append((_label(s, e, spans, starts), e - s))
+                label = _label(s, e, spans, starts)
+                gaps.append((label, e - s))
+                _cut(s, e, label, pieces, piece_starts, idle_ns)
     if not busy:
         raise ValueError("the trace holds no device plane")
     return Reduced(window_ns=t1 - t0, busy_ns=sum(busy) / len(busy), op_ns=op_ns,
-                   gaps=gaps, n_devices=len(busy))
+                   gaps=gaps, n_devices=len(busy), span_ns=span_ns(trace),
+                   idle_ns=idle_ns)
