@@ -54,6 +54,7 @@ __all__ = [
     "get_backend",
     "register",
     "resolve_scheme",
+    "serves_window_features",
     "unregister",
 ]
 
@@ -98,7 +99,11 @@ class Backend:
     ``host_fn(stack_np, spec, quant)`` (optional, for ``caps.host_native``)
     is a plain-NumPy counting path — (B, *spatial) ndarray in, integer
     count ndarray out, regions included — that the plan invokes outside
-    jit when the input is concrete.
+    jit when the input is concrete.  ``window_features(img_batch, spec,
+    names, quant=None)`` (optional) returns the named features of every
+    window straight from the image, (B, gh, gw, n_pairs, len(names))
+    float32, with no count matrix in between; the plan takes it where
+    :func:`serves_window_features` says it applies.
     """
 
     name: str
@@ -108,6 +113,7 @@ class Backend:
     local_partial: Callable[..., jax.Array] | None = None
     region_compute: Callable[..., jax.Array] | None = None
     host_fn: Callable[..., object] | None = None
+    window_features: Callable[..., jax.Array] | None = None
 
 
 def supports_ndim(backend: Backend, ndim: int) -> bool:
@@ -115,6 +121,24 @@ def supports_ndim(backend: Backend, ndim: int) -> bool:
     if ndim == 3:
         return backend.caps.volumetric
     return not backend.caps.volume_only
+
+
+def serves_window_features(backend: Backend, spec: GLCMSpec, names) -> bool:
+    """Whether ``backend`` hands back the features ``names`` of ``spec``
+    directly: a symmetric stride-1 window spec over 2-D images, every name
+    one the window-features kernel computes, and few enough levels for its
+    unrolled level pairs. Any other spec is counted first and its features
+    taken from the counts."""
+    return (
+        backend.window_features is not None
+        and spec.region == "window"
+        and spec.ndim == 2
+        and spec.symmetric
+        and spec.strides == (1, 1)
+        and spec.levels <= kops.WINDOW_MAX_LEVELS
+        and bool(names)
+        and set(names) <= set(kops.WINDOW_FEATURES)
+    )
 
 
 def compute_regions(
@@ -387,6 +411,16 @@ def _pallas_fused_region_compute(img: jax.Array, spec: GLCMSpec, quant=None) -> 
     ).astype(jnp.float32)
 
 
+def _pallas_window_features(img: jax.Array, spec: GLCMSpec, names,
+                            quant=None) -> jax.Array:
+    # Every window's features in one kernel launch over the raw image: no
+    # extracted patches, no per-window counts in HBM.
+    return kops.glcm_pallas_window_features(
+        img, spec.levels, spec.pairs, spec.region_shape, tuple(names),
+        quant=quant,
+    )
+
+
 def _pallas_volume_compute(img: jax.Array, spec: GLCMSpec, quant=None) -> jax.Array:
     return kops.glcm_pallas_volume(
         img, spec.levels, spec.pairs, slab_d=spec.slab_d, copies=spec.copies,
@@ -490,6 +524,7 @@ register(
             region_grid=True, fused_quantize=True,
         ),
         region_compute=_pallas_fused_region_compute,
+        window_features=_pallas_window_features,
     )
 )
 register(

@@ -14,6 +14,11 @@ f4  Sum of Squares: Variance           f11 Difference Entropy
 f5  Inverse Difference Moment          f12 Information Measure of Corr. 1
 f6  Sum Average                        f13 Information Measure of Corr. 2
 f7  Sum Variance                       f14 Max. Correlation Coefficient
+
+Two more may be selected by name (``select=``), never part of the default
+fourteen: ``cluster_shade`` Σ (i + j − μx − μy)³ p and
+``cluster_prominence`` Σ (i + j − μx − μy)⁴ p (Conners, Trivedi & Harlow
+1984; Orfeo ToolBox's "simple" texture set).
 """
 
 from __future__ import annotations
@@ -21,7 +26,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["haralick_features", "FEATURE_NAMES", "normalize_glcm"]
+__all__ = [
+    "haralick_features",
+    "FEATURE_NAMES",
+    "SELECTABLE_FEATURES",
+    "normalize_glcm",
+]
 
 FEATURE_NAMES = (
     "asm_energy",
@@ -40,6 +50,9 @@ FEATURE_NAMES = (
     "max_correlation_coefficient",
 )
 
+# Selectable by name only: features=True / select=None give FEATURE_NAMES.
+SELECTABLE_FEATURES = FEATURE_NAMES + ("cluster_shade", "cluster_prominence")
+
 _EPS = 1e-12
 
 
@@ -56,7 +69,8 @@ def _entropy(p: jax.Array, axis=None) -> jax.Array:
 def _haralick_single(p: jax.Array, select: tuple[int, ...]) -> jax.Array:
     """(L, L) normalized GLCM → (len(select),) feature vector.
 
-    ``select`` holds FEATURE_NAMES indices, output columns follow its order.
+    ``select`` holds SELECTABLE_FEATURES indices, output columns follow its
+    order.
     f1–f13 are O(L²) and always computed; the O(L³) eigendecomposition of
     f14 (max_correlation_coefficient) is traced ONLY when index 13 is
     selected — for texture maps with thousands of windows per image it
@@ -108,7 +122,8 @@ def _haralick_single(p: jax.Array, select: tuple[int, ...]) -> jax.Array:
     f12 = (hxy - hxy1) / jnp.maximum(jnp.maximum(hx, hy), _EPS)
     f13 = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(-2.0 * (hxy2 - hxy)), 0.0))
 
-    feats = [f1, f2, f3, f4, f5, f6, f7, f8, f9, f10, f11, f12, f13]
+    feats = dict(enumerate([f1, f2, f3, f4, f5, f6, f7, f8, f9, f10, f11,
+                            f12, f13]))
 
     if 13 in select:
         # f14: sqrt of second-largest eigenvalue of Q, Q[i,j] = Σ_k p[i,k]
@@ -122,7 +137,12 @@ def _haralick_single(p: jax.Array, select: tuple[int, ...]) -> jax.Array:
         # HIGHEST: the TPU's default f32 matmul precision is one bf16 pass.
         gram = jnp.matmul(a_mat, a_mat.T, precision=jax.lax.Precision.HIGHEST)
         eig = jnp.linalg.eigvalsh(gram)
-        feats.append(jnp.sqrt(jnp.clip(jnp.sort(eig)[-2], 0.0, None)))
+        feats[13] = jnp.sqrt(jnp.clip(jnp.sort(eig)[-2], 0.0, None))
+
+    if 14 in select or 15 in select:
+        dev = ii + jj - mu_x - mu_y
+        feats[14] = jnp.sum(dev**3 * p)   # cluster shade
+        feats[15] = jnp.sum(dev**4 * p)   # cluster prominence
 
     return jnp.stack([feats[i] for i in select])
 
@@ -132,12 +152,12 @@ def _select_indices(select: tuple[str, ...] | None) -> tuple[int, ...]:
         return tuple(range(len(FEATURE_NAMES)))
     idx = []
     for name in select:
-        if name not in FEATURE_NAMES:
+        if name not in SELECTABLE_FEATURES:
             raise ValueError(
                 f"unknown Haralick feature {name!r}; expected names from "
-                f"{FEATURE_NAMES}"
+                f"{SELECTABLE_FEATURES}"
             )
-        idx.append(FEATURE_NAMES.index(name))
+        idx.append(SELECTABLE_FEATURES.index(name))
     if not idx:
         raise ValueError("select=() names no features")
     return tuple(idx)
@@ -153,8 +173,8 @@ def haralick_features(
 
     Accepts (..., L, L); returns (..., n_feats). Raw counts are normalized
     unless ``assume_normalized``. ``select`` names a subset of
-    :data:`FEATURE_NAMES` — output columns follow its order, and work the
-    selection doesn't need is skipped (only the O(L³) eigendecomposition of
+    :data:`SELECTABLE_FEATURES` — output columns follow its order, and work
+    the selection doesn't need is skipped (only the O(L³) eigendecomposition of
     ``max_correlation_coefficient`` is expensive enough to matter). The
     default ``None`` computes all 14 in canonical order.
     """

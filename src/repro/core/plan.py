@@ -56,6 +56,13 @@ feature names — a subset skips work the selection doesn't need (notably the
 O(L³) eigendecomposition of ``max_correlation_coefficient``, which dominates
 texture-map feature cost).
 
+Where the backend can hand back the requested features of a window spec
+directly (``backends.serves_window_features``: a stride-1 window over 2-D
+images, features all among the window-features kernel's), the plan takes
+them from it — (B, gh, gw, n_pairs, n_feats) float32 with no count matrix,
+float32 cast or per-window feature tail in the program — and says so in
+``GLCMPlan.window_features``.  Every other spec counts first.
+
 Volumetric specs (``spec.ndim == 3``) run the same pipeline over (D, H, W)
 volumes / (B, D, H, W) stacks: the spec's rank disambiguates a 3-length
 shape, offsets/regions validate against the (D, H, W) extents pre-trace,
@@ -83,7 +90,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import backends as _backends
-from repro.core.haralick import FEATURE_NAMES, haralick_features
+from repro.core.haralick import (
+    FEATURE_NAMES,
+    SELECTABLE_FEATURES,
+    haralick_features,
+)
 from repro.core.quantize import (
     is_identity_quantize,
     quantize_equalized,
@@ -127,6 +138,8 @@ class GLCMPlan:
     fused_quantize: bool = False   # quantization is binned inside the count
     host_native: bool = False      # fn runs NumPy counting outside jit
     tuned: object = None           # the autotune.TunedChoice applied, if any
+    window_features: bool = False  # features straight from the backend's
+    #                                window-features path, no counts
     lint: tuple | None = None      # analysis.Finding tuple once linted
     #                                (empty = verified clean; None = unlinted)
 
@@ -235,10 +248,10 @@ def _canonical_features(features) -> bool | tuple[str, ...]:
         return features
     names = tuple(features)
     for name in names:
-        if name not in FEATURE_NAMES:
+        if name not in SELECTABLE_FEATURES:
             raise ValueError(
                 f"unknown Haralick feature {name!r}; expected names from "
-                f"{FEATURE_NAMES}"
+                f"{SELECTABLE_FEATURES}"
             )
     if not names:
         raise ValueError("features=() selects nothing; pass False instead")
@@ -518,6 +531,10 @@ def compile_plan(
         plan = _cache_put(key, plan)
         return _ensure_linted(plan) if check == "lint" else plan
 
+    names = FEATURE_NAMES if features is True else features
+    direct = bool(features) and _backends.serves_window_features(
+        backend, resolved, names)
+
     def run(img: jax.Array) -> jax.Array:
         if fused:
             stack = img if batched else img[None]
@@ -544,6 +561,9 @@ def compile_plan(
             img = img.astype(jnp.int32)
             stack = img if batched else img[None]
             qargs = None
+        if direct:
+            out = backend.window_features(stack, resolved, names, quant=qargs)
+            return out if batched else out[0]
         mats = _backends.compute_regions(
             backend, stack, resolved, quant=qargs
         ).astype(jnp.float32)
@@ -590,7 +610,7 @@ def compile_plan(
     plan = GLCMPlan(
         spec=resolved, backend=backend, shape=shape, features=features,
         fn=fn, grid=grid, fused_quantize=fused, host_native=host,
-        tuned=tuned,
+        tuned=tuned, window_features=direct,
     )
     _note_compile(resolved, shape, "plan", t_build, t_build_tr)
     plan = _cache_put(key, plan)

@@ -50,6 +50,13 @@ Four kernels:
     directions at once; the inter-slice halo (dz > 0) is the NEXT slab,
     DMA'd via a second input Ref exactly like the fused kernel's next tile.
 
+``glcm_window_features_pallas`` — dense texture maps: every stride-1
+    window's features straight from the raw image. No patch is extracted
+    and no count leaves VMEM: the grid runs over (B, row strips, column
+    chunks) with window columns on lanes, each level pair's indicator plane
+    is box-summed over the window's pair positions by lane and sublane
+    rotations, and the features are accumulated over the level pairs.
+
 The accumulating kernels carry a **batch grid axis**: the grid is
 (B, steps) and the output ``index_map`` pins each image's accumulator to its
 batch slot, so a stack is processed in ONE ``pallas_call`` launch. Grid
@@ -81,6 +88,9 @@ __all__ = [
     "glcm_fused_pallas",
     "glcm_window_pallas",
     "glcm_volume_pallas",
+    "glcm_window_features_pallas",
+    "WINDOW_FEATURES",
+    "WINDOW_MAX_LEVELS",
     "DEFAULT_CHUNK",
     "DEFAULT_COPIES",
     "DEFAULT_SLAB_D",
@@ -705,4 +715,240 @@ def glcm_volume_pallas(
         interpret=interpret,
     )(*args)
     out = out[..., :levels, :levels]
+    return out if batched else out[0]
+
+
+# ---------------------------------------------------------------------------
+# Kernel 5: stride-1 window features — counts stay in VMEM, features leave
+# ---------------------------------------------------------------------------
+
+# The features the window-features kernel computes, in the definitions of
+# ``core.haralick`` (the two cluster features as Conners, Trivedi & Harlow
+# 1984 give them).
+WINDOW_FEATURES = (
+    "asm_energy",
+    "contrast",
+    "correlation",
+    "inverse_difference_moment",
+    "entropy",
+    "cluster_shade",
+    "cluster_prominence",
+)
+# The L(L+1)/2 level pairs are unrolled in the kernel body.
+WINDOW_MAX_LEVELS = 16
+_EPS = 1e-12          # core.haralick's log / division guard
+_HALO_LANES = 128     # columns read past a chunk: the window's right edge
+
+
+def _shift(x: jax.Array, k: int, axis: int) -> jax.Array:
+    """``x[..., i + k, ...]`` along ``axis``, wrapping at the end."""
+    n = x.shape[axis]
+    return pltpu.roll(x, (-k) % n, axis) if k % n else x
+
+
+def _run_sum(x: jax.Array, n: int, axis: int) -> jax.Array:
+    """Σ_{t<n} x[..., i + t, ...] along ``axis`` by doubling: about 2·log2(n)
+    rotations instead of n − 1."""
+    total, off, span, block = None, 0, 1, x
+    while True:
+        if n & 1:
+            part = _shift(block, off, axis)
+            total = part if total is None else total + part
+            off += span
+        n >>= 1
+        if not n:
+            return total
+        block = block + _shift(block, span, axis)
+        span *= 2
+
+
+def _window_features_kernel(
+    *refs,
+    levels: int,
+    offsets: tuple[tuple[int, int], ...],
+    rh: int,
+    rw: int,
+    th: int,
+    cw: int,
+    features: tuple[str, ...],
+    fused_quant: bool,
+):
+    # refs is (cur, cur_right, nxt, nxt_right, [q,] o): this step's (th, cw)
+    # block of the image, the 128 columns to its right, and the same two of
+    # the next row strip (the window's bottom rows); q is the (B, 2) (lo,
+    # span) SMEM table when quantization is fused. o is the (n_off·n_feat,
+    # th, cw) block of feature planes of the windows whose top-left pixel
+    # lies in this block.
+    cur, cur_r, nxt, nxt_r, o_ref = refs[0], refs[1], refs[2], refs[3], refs[-1]
+    quant = None
+    if fused_quant:
+        bi = pl.program_id(0)
+        quant = (refs[4][bi, 0], refs[4][bi, 1])
+    q = _bin(jnp.concatenate([
+        jnp.concatenate([cur[0], cur_r[0]], axis=1),
+        jnp.concatenate([nxt[0], nxt_r[0]], axis=1),
+    ], axis=0), levels, quant)                       # (2·th, cw + 128) levels
+    basic = {"asm_energy", "contrast", "inverse_difference_moment",
+             "entropy"} & set(features)
+    moments = {"correlation", "cluster_shade", "cluster_prominence"} & set(features)
+    plane = (th, cw)
+    for k, (dy, dx) in enumerate(offsets):
+        # The pair anchored at (y, x) is (q[y, x], q[y + dy, x + dx]); window
+        # (y, x) holds the pairs anchored in rows y .. y + nh − 1 and columns
+        # x + c0 .. x + c0 + nw − 1. Every stride-1 window lies inside the
+        # image, so each casts 2·nh·nw symmetric votes.
+        nh, nw, c0 = rh - dy, rw - abs(dx), max(0, -dx)
+        ref_q = _shift(_shift(q, dy, 0), dx, 1)
+        code = jnp.minimum(q, ref_q) * levels + jnp.maximum(q, ref_q)
+        inv_total = 1.0 / (2 * nh * nw)
+        acc = {f: jnp.zeros(plane, jnp.float32) for f in basic}
+        px = [jnp.zeros(plane, jnp.float32) for _ in range(levels)]
+        psum = [jnp.zeros(plane, jnp.float32) for _ in range(2 * levels - 1)]
+        for i in range(levels):
+            for j in range(i, levels):
+                # p[i, j] = p[j, i] of every window at once: the box sum of
+                # this level pair's indicator plane over the window's pair
+                # positions. Off the diagonal the cell stands for m = 2
+                # entries; on it, both votes of a pair land in the one cell.
+                hit = (code == i * levels + j).astype(jnp.float32)
+                s = _run_sum(hit, nh, 0)[:th]
+                s = _shift(_run_sum(s, nw, 1), c0, 1)[:, :cw]
+                p = s * ((2 if i == j else 1) * inv_total)
+                m = 1.0 if i == j else 2.0
+                d2 = float((i - j) ** 2)
+                if "asm_energy" in basic:
+                    acc["asm_energy"] += (m * p) * p
+                if "entropy" in basic:
+                    acc["entropy"] += (m * p) * jnp.log(p + _EPS)
+                if "contrast" in basic:
+                    acc["contrast"] += (m * d2) * p
+                if "inverse_difference_moment" in basic:
+                    acc["inverse_difference_moment"] += (m / (1.0 + d2)) * p
+                if moments:
+                    px[i] = px[i] + p
+                    if i != j:
+                        px[j] = px[j] + p
+                    psum[i + j] = psum[i + j] + m * p
+        res = dict(acc)
+        if "entropy" in res:
+            res["entropy"] = -res["entropy"]
+        if moments:
+            # Centered moments from the marginal (μx = μy, σx = σy) and the
+            # sum distribution p_{x+y}: with s = i + j, Var(s) = 2σ² + 2·cov,
+            # so cov needs no second pass over the level pairs.
+            mu = sum(float(i) * px[i] for i in range(levels))
+            var = sum((float(i) - mu) ** 2 * px[i] for i in range(levels))
+            dev = [float(v) - 2.0 * mu for v in range(2 * levels - 1)]
+            if "correlation" in moments:
+                cov = 0.5 * sum(d * d * ps for d, ps in zip(dev, psum)) - var
+                sd = jnp.sqrt(jnp.maximum(var, 0.0))
+                res["correlation"] = cov / jnp.maximum(sd * sd, _EPS)
+            if "cluster_shade" in moments:
+                res["cluster_shade"] = sum(d * d * d * ps
+                                           for d, ps in zip(dev, psum))
+            if "cluster_prominence" in moments:
+                res["cluster_prominence"] = sum((d * d) * (d * d) * ps
+                                                for d, ps in zip(dev, psum))
+        for n, f in enumerate(features):
+            o_ref[0, k * len(features) + n] = res[f]
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("levels", "offsets", "window", "features", "interpret"),
+)
+def glcm_window_features_pallas(
+    img: jax.Array,
+    *,
+    levels: int,
+    offsets: tuple[tuple[int, int], ...],
+    window: tuple[int, int],
+    features: tuple[str, ...],
+    quant=None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Haralick features of every stride-1 window of image(s) (float32).
+
+    ``img`` is (H, W) → (gh, gw, n_offsets, n_features), or (B, H, W) →
+    (B, gh, gw, n_offsets, n_features), with (gh, gw) = (H − rh + 1,
+    W − rw + 1) for ``window`` = (rh, rw): the answer layout of a window
+    region spec. ``features`` names a subset of :data:`WINDOW_FEATURES`,
+    in the output's column order; each is computed from the window's
+    symmetric (P + Pᵀ), normalized GLCM as ``core.haralick`` defines it.
+
+    Nothing per window reaches HBM but its features. The grid is
+    (B, row strips of ``th`` windows, column chunks of ``cw`` windows): each
+    step reads its (th, cw) block of the image plus the 128 columns to its
+    right and the same of the next strip (the next-tile halo of
+    ``glcm_fused_pallas``), bins it in-register (``quant=(lo, span)`` as in
+    the other kernels, else the input holds levels), and forms, per offset,
+    the plane of pair codes. For each level pair, the indicator plane of its
+    code summed over the window's (rh − dy) × (rw − |dx|) pair positions by
+    sublane and lane rotations is that pair's count in every window of the
+    block at once; the features are accumulated over the level pairs.
+    """
+    if img.ndim not in (2, 3):
+        raise ValueError(f"expected (H, W) or (B, H, W) image, got {img.shape}")
+    unknown = [f for f in features if f not in WINDOW_FEATURES]
+    if unknown or not features:
+        raise ValueError(f"features must name some of {WINDOW_FEATURES}, "
+                         f"got {features!r}")
+    if levels > WINDOW_MAX_LEVELS:
+        raise ValueError(f"levels={levels} exceeds {WINDOW_MAX_LEVELS}")
+    batched = img.ndim == 3
+    h, w = img.shape[-2:]
+    rh, rw = window
+    if not (1 <= rh <= h and 1 <= rw <= w) or rw > _HALO_LANES + 1:
+        raise ValueError(f"window {window} does not fit image ({h}, {w})")
+    for dy, dx in offsets:
+        if not (0 <= dy < rh) or abs(dx) >= rw:
+            raise ValueError(f"offset (dy={dy}, dx={dx}) does not fit window {window}")
+    gh, gw = h - rh + 1, w - rw + 1
+    th = max(8, -(-(rh - 1) // 8) * 8)          # the halo fits in one strip
+    cw = min(512, -(-gw // 128) * 128)
+    strips, chunks = -(-gh // th), -(-gw // cw)
+    hp, wp = (strips + 1) * th, chunks * cw + _HALO_LANES
+    x = img.astype(jnp.float32 if quant is not None else jnp.int32)
+    if not batched:
+        x = x[None]
+    b = x.shape[0]
+    # Rows and columns past the image only reach windows past (gh, gw),
+    # which are cut off below.
+    x = jnp.pad(x, ((0, 0), (0, hp - h), (0, wp - w)))
+    n_out = len(offsets) * len(features)
+    step = cw // _HALO_LANES
+
+    in_specs = [
+        pl.BlockSpec((1, th, cw), lambda bi, i, j: (bi, i, j)),
+        pl.BlockSpec((1, th, _HALO_LANES), lambda bi, i, j: (bi, i, (j + 1) * step)),
+        pl.BlockSpec((1, th, cw), lambda bi, i, j: (bi, i + 1, j)),
+        pl.BlockSpec((1, th, _HALO_LANES),
+                     lambda bi, i, j: (bi, i + 1, (j + 1) * step)),
+    ]
+    args = [x, x, x, x]
+    if quant is not None:
+        in_specs.append(_quant_spec())
+        args.append(_quant_table(quant, b))
+
+    out = pl.pallas_call(
+        functools.partial(
+            _window_features_kernel,
+            levels=levels,
+            offsets=tuple(offsets),
+            rh=rh,
+            rw=rw,
+            th=th,
+            cw=cw,
+            features=tuple(features),
+            fused_quant=quant is not None,
+        ),
+        grid=(b, strips, chunks),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, n_out, th, cw), lambda bi, i, j: (bi, 0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((b, n_out, strips * th, chunks * cw),
+                                       jnp.float32),
+        interpret=interpret,
+    )(*args)
+    out = out[:, :, :gh, :gw].reshape(b, len(offsets), len(features), gh, gw)
+    out = out.transpose(0, 3, 4, 1, 2)
     return out if batched else out[0]

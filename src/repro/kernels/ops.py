@@ -23,9 +23,12 @@ from repro.kernels.glcm_kernel import (
     DEFAULT_CHUNK,
     DEFAULT_COPIES,
     DEFAULT_SLAB_D,
+    WINDOW_FEATURES,
+    WINDOW_MAX_LEVELS,
     glcm_fused_pallas,
     glcm_volume_pallas,
     glcm_vote_pallas,
+    glcm_window_features_pallas,
     glcm_window_pallas,
 )
 from repro.kernels.histogram_kernel import histogram_pallas
@@ -34,6 +37,7 @@ __all__ = [
     "glcm_pallas",
     "glcm_pallas_multi",
     "glcm_pallas_volume",
+    "glcm_pallas_window_features",
     "glcm_pallas_windowed",
     "histogram",
     "onehot_count",
@@ -208,6 +212,32 @@ def glcm_pallas_windowed(
         levels=levels,
         offsets=offsets,
         copies=copies,
+        interpret=should_interpret(interpret),
+        quant=quant,
+    )
+
+
+def glcm_pallas_window_features(
+    img: jax.Array,
+    levels: int,
+    pairs: tuple[tuple[int, int], ...],
+    window: tuple[int, int],
+    features: tuple[str, ...],
+    *,
+    interpret: bool | None = None,
+    quant=None,
+) -> jax.Array:
+    """Features of every stride-1 ``window``'s symmetric GLCM of image(s)
+    via the window-features kernel: (H, W) → (gh, gw, len(pairs),
+    len(features)) float32, or (B, H, W) → (B, gh, gw, ...). ``pairs`` are
+    (d, theta) tuples; ``features`` names a subset of ``WINDOW_FEATURES``."""
+    offsets = tuple(_ref.glcm_offsets(d, t) for d, t in pairs)
+    return glcm_window_features_pallas(
+        img,
+        levels=levels,
+        offsets=offsets,
+        window=tuple(window),
+        features=tuple(features),
         interpret=should_interpret(interpret),
         quant=quant,
     )

@@ -38,8 +38,10 @@ job is to keep launches *full and frequent* under real traffic:
   on the device, ``launch_ms`` from there until the output is ready; a
   one-image bucket is handed to the copy as a view of the request's own
   array, so its pad builds no batch), a batch-occupancy histogram, shed,
-  result-eviction and unstacked-batch counters — plus the engine-wide
-  plan-cache hit rate.  Counters/gauges/latency histograms also stream
+  result-eviction and unstacked-batch counters, the bytes of answers read
+  back and the regions (windows, tiles or whole requests) answered — plus
+  the engine-wide plan-cache hit rate.  Counters/gauges/latency histograms
+  also stream
   into the process-global :mod:`repro.obs.metrics` registry (Prometheus
   text via ``get_registry().to_prometheus()``).
 * **Tracing.**  Each dispatch runs inside live spans: ``glcm.dispatch``
@@ -72,6 +74,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import math
 import time
 
 import jax
@@ -281,6 +284,10 @@ class _Workload:
         self.results_evicted = 0
         self.batches = 0
         self.unstacked_batches = 0
+        self.answer_bytes = 0
+        self.windows = 0
+        # regions answered per request: the window or tile grid, else one
+        self.regions = math.prod(spec.region_grid(*image_shape))
         self.deadline_dispatches = 0
         self.occupancy: dict[int, dict[int, int]] = {}  # bucket → {occ: n}
         self.queue_ms: collections.deque = collections.deque(maxlen=stats_window)
@@ -307,6 +314,13 @@ class _Workload:
         self.m_unstacked = reg.counter(
             "repro_serve_unstacked_batches_total",
             "batches handed to the copy as the request's own buffer",
+            workload=name)
+        self.m_answer_bytes = reg.counter(
+            "repro_serve_answer_bytes_total",
+            "bytes of answers read back from the device", workload=name)
+        self.m_windows = reg.counter(
+            "repro_serve_windows_total",
+            "regions answered (a window, a tile, or a whole request)",
             workload=name)
         self.m_deadline = reg.counter(
             "repro_serve_deadline_dispatches_total",
@@ -762,6 +776,8 @@ class GLCMEngine:
                 "results_evicted": w.results_evicted,
                 "batches": w.batches,
                 "unstacked_batches": w.unstacked_batches,
+                "answer_bytes": w.answer_bytes,
+                "windows": w.windows,
                 "deadline_dispatches": w.deadline_dispatches,
                 "batch_occupancy": {
                     b: dict(h) for b, h in sorted(w.occupancy.items())
@@ -902,6 +918,10 @@ class GLCMEngine:
         if unstacked:
             w.unstacked_batches += 1
             w.m_unstacked.inc()
+        w.answer_bytes += out.nbytes
+        w.windows += k * w.regions
+        w.m_answer_bytes.inc(out.nbytes)
+        w.m_windows.inc(k * w.regions)
         if deadline:
             w.deadline_dispatches += 1
             w.m_deadline.inc()
